@@ -2,8 +2,8 @@
 from repro.distributed.mesh_utils import (
     corpus_mesh,
     make_mesh,
+    make_production_mesh,
     mesh_device_count,
     named_sharding,
-    shard_map_compat,
 )
 from repro.distributed.partition import ShardingPolicy

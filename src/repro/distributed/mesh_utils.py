@@ -1,7 +1,8 @@
-"""Mesh construction helpers shared by launch/ and tests.
+"""Mesh construction helpers shared by launch/, retrieval/ and tests.
 
-``jax.make_mesh`` defaults will flip axis_types to Explicit in jax 0.9; we
-pin Auto explicitly so pjit/shard_map semantics stay stable across versions.
+Every mesh pins ``AxisType.Auto`` so pjit/shard_map keep their implicit
+sharding semantics. Meshes are built by functions, never module-level
+constants, so importing this module never touches jax device state.
 """
 
 from __future__ import annotations
@@ -9,18 +10,21 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-try:  # AxisType landed after 0.4.x; older jax is implicitly Auto everywhere
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes))
+
+
+def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
+    """Single-pod: 16×16 = 256 chips, axes (data, model). Multi-pod:
+    2×16×16 = 512 chips, axes (pod, data, model) — the pod axis is the
+    slower DCN/ICI dimension that gradient all-reduce crosses."""
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def corpus_mesh(n_shards: int, axis: str = "data") -> Mesh:
@@ -34,8 +38,6 @@ def corpus_mesh(n_shards: int, axis: str = "data") -> Mesh:
     --xla_force_host_platform_device_count=N`` for CPU hosts) when the host
     has too few devices.
     """
-    import numpy as np
-
     devices = jax.devices()
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")
@@ -45,24 +47,7 @@ def corpus_mesh(n_shards: int, axis: str = "data") -> Mesh:
             "hosts set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{n_shards} before importing jax, or use execution='threads'"
         )
-    if AxisType is None:
-        return Mesh(np.asarray(devices[:n_shards]), (axis,))
     return Mesh(np.asarray(devices[:n_shards]), (axis,), axis_types=(AxisType.Auto,))
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across the rename: new jax exposes it top-level with
-    ``check_vma``; 0.4.x has ``jax.experimental.shard_map`` with the
-    ``check_rep`` spelling of the same knob."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
 
 
 def named_sharding(mesh: Mesh, *spec) -> NamedSharding:

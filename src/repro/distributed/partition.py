@@ -1,6 +1,6 @@
 """Partition specs: how every tensor in the system shards over the mesh.
 
-Mesh axes (launch/mesh.py): ``("data", "model")`` single-pod,
+Mesh axes (distributed/mesh_utils.py): ``("data", "model")`` single-pod,
 ``("pod", "data", "model")`` multi-pod. Policy:
 
 * **DP**   — batch over ``(pod, data)``.

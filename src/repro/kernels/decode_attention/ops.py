@@ -1,7 +1,10 @@
 """jit'd wrapper for decode attention + the distributed (SP) combine.
 
-``decode_attention`` — single-device dispatch (Pallas on TPU, oracle
-elsewhere). ``decode_attention_sharded_body`` — the shard_map body for a KV
+``decode_attention`` — single-device dispatch: the jnp oracle unless the
+caller asks for the Pallas kernel. The kernel is never picked on its own:
+the TPU compiler refuses its ``(1, bk, 1, dh)`` KV block over a
+``(B, S, Hk, dh)`` cache, which breaks the (8, 128) tiling rule, so it runs
+only in interpret mode until its layout is repaired. ``decode_attention_sharded_body`` — the shard_map body for a KV
 cache sharded along the sequence axis: each shard computes partial
 (out·l, l, m) and the shards combine with a max/logsumexp reduction over the
 mesh axis, which is exactly FlashDecoding's split-K reduction lifted to the
@@ -28,10 +31,9 @@ def decode_attention(
     lengths: jnp.ndarray,
     *,
     block_k: int = 512,
-    use_pallas: bool | None = None,
+    use_pallas: bool = False,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    use_pallas = (jax.default_backend() == "tpu") if use_pallas is None else use_pallas
     if use_pallas:
         return decode_attention_pallas(
             q, k, v, lengths, block_k=block_k, interpret=interpret

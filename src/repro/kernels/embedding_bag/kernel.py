@@ -25,10 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams → CompilerParams; support both so the kernel
-# runs (interpret or compiled) on either side of the rename.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _bag_kernel(idx_ref, seg_ref, table_row_ref, out_ref):
     i = pl.program_id(0)
@@ -65,7 +61,7 @@ def embedding_bag_pallas(
         _bag_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_bags, d), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -26,10 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams → CompilerParams; support both so the kernel
-# runs (interpret or compiled) on either side of the rename.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -127,7 +123,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),  # l
             pltpu.VMEM((bq, dh), jnp.float32),  # acc
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -29,9 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams → CompilerParams; support both so the kernel
-# runs (interpret or compiled) on either side of the rename.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import SCORE_PRECISION
 
 NEG_INF = -1e30
 
@@ -78,7 +76,8 @@ def _mips_kernel(q_ref, c_ref, v_out, i_out, bv_ref, bi_ref, *, k, bn, n_c, n_va
     q = q_ref[...].astype(jnp.float32)  # (bq, D)
     c = c_ref[...].astype(jnp.float32)  # (bn, D)
     scores = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, c, (((1,), (1,)), ((), ())),
+        precision=SCORE_PRECISION, preferred_element_type=jnp.float32,
     )  # (bq, bn)
     if n_valid < n_c * bn:  # corpus was zero-padded: mask the pad columns out
         col = ic * bn + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
@@ -113,7 +112,8 @@ def _mips_kernel_masked(q_ref, c_ref, m_ref, v_out, i_out, bv_ref, bi_ref, *, k,
     q = q_ref[...].astype(jnp.float32)  # (bq, D)
     c = c_ref[...].astype(jnp.float32)  # (bn, D)
     scores = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, c, (((1,), (1,)), ((), ())),
+        precision=SCORE_PRECISION, preferred_element_type=jnp.float32,
     )  # (bq, bn)
     mask = m_ref[...] > 0.0  # (1, bn), broadcasts over query rows
     scores = jnp.where(mask, scores, NEG_INF)
@@ -177,7 +177,7 @@ def mips_topk_pallas(
             pltpu.VMEM((bq, k), jnp.float32),
             pltpu.VMEM((bq, k), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -31,7 +31,7 @@ def run_cell(arch_name: str, shape: str, multi_pod: bool, *, policy_overrides=No
 
     from repro.configs.base import get_arch, policy_for_mesh
     from repro.launch.hlo_analysis import analyze_compiled
-    from repro.launch.mesh import make_production_mesh
+    from repro.distributed.mesh_utils import make_production_mesh
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_devices = 1

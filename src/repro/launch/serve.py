@@ -164,7 +164,9 @@ _ENGINE_OPT_KEYS = (
 )
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    """The serve CLI's argument parser; ``parse_args([])`` gives its
+    defaults (``chip_smoke.py`` builds its engines from these)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--docs", default=None, help="newline-separated passages (default: paper corpus)")
     ap.add_argument("--questions", default=None, help="one query per line (default: paper queries)")
@@ -210,11 +212,13 @@ def main() -> None:
         choices=("threads", "process", "device", "auto"),
         help="how sharded search runs: 'threads' fans per-shard searches out "
         "on host threads; 'process' fans out to persistent per-shard worker "
-        "processes (GIL-free — the multi-core host path); 'device' lowers "
+        "processes (GIL-free — the multi-core CPU host path; refused on an "
+        "accelerator, whose chip this process holds); 'device' lowers "
         "search + top-k merge onto the jax device mesh as one shard_map "
         "program (requires >= S devices; on CPU hosts set "
         "XLA_FLAGS=--xla_force_host_platform_device_count=S); 'auto' picks "
-        "inline threads or process by core count. All are bit-identical to "
+        "inline threads or process by core count, and never process on an "
+        "accelerator. All are bit-identical to "
         "unsharded retrieval (docs/retrieval.md)",
     )
     ap.add_argument(
@@ -299,15 +303,24 @@ def main() -> None:
         help="where the pipeline's middle stages run (--stream only): "
         "'thread' = in-process worker threads (GIL-bound); 'process' = "
         "spawn-context worker processes that each rebuild this engine once "
-        "and drain micro-batches GIL-free. Records are bit-identical "
-        "either way (docs/serving.md)",
+        "and drain micro-batches GIL-free (CPU hosts only: refused on an "
+        "accelerator, whose chip this process holds). Records are "
+        "bit-identical either way (docs/serving.md)",
     )
     ap.add_argument("--tokens-per-s", type=float, default=None,
                     help="pace the slot decoder's step clock (--stream only; "
                     "default: free-running)")
     ap.add_argument("--seed", type=int, default=0, help="arrival-trace seed (--stream)")
+    return ap
+
+
+def main() -> None:
+    ap = build_parser()
     args = ap.parse_args()
 
+    from repro.runtime import enable_compilation_cache
+
+    enable_compilation_cache()
     if args.scenario is not None:
         import json
 

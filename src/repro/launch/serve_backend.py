@@ -10,6 +10,10 @@ named backend, and every client-side decorator (cache, faults, resilience)
 wraps the network hop unchanged. The service can itself shard — ``--shards``
 builds the served backend through the same declarative stack the engine
 uses, so a remote dense backend can fan out across shards server-side.
+
+The service and its client each build jax state, so on a TPU host they do
+not share one chip: a chip belongs to one process at a time. Run the
+service on a host of its own, or keep the backend in the serving process.
 """
 
 from __future__ import annotations
@@ -95,7 +99,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.retrieval.remote import BackendServer
+    from repro.runtime import enable_compilation_cache
 
+    enable_compilation_cache()
     backend = build_served_backend(args)
     server = BackendServer(backend, host=args.host, port=args.port, fmt=args.format)
     print(

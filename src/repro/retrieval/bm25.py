@@ -186,8 +186,8 @@ class BM25Index:
 
     # -- device search path ----------------------------------------------------
     def _search_fn(self, k: int, e_pad: int):
-        """Cached jit closure ``(sel (E,), seg (E,)) → ((Q_BLOCK, k),
-        (Q_BLOCK, k))`` — segment-sum scoring into a fixed
+        """Cached jit program ``(post_contrib, sel (E,), seg (E,)) →
+        ((Q_BLOCK, k), (Q_BLOCK, k))`` — segment-sum scoring into a fixed
         ``(Q_BLOCK, n_passages)`` block, on-device ``lax.top_k``, sentinel
         masking. Compiled once per (k, edge bucket); every shape in the
         program is static, so batch sizes never retrace.
@@ -206,9 +206,11 @@ class BM25Index:
         n = self.n_passages
         num_segments = Q_BLOCK * n + 1  # + the pad dummy segment
 
-        def core(sel: jnp.ndarray, seg: jnp.ndarray):
+        # the posting contributions are an argument, never a captured
+        # constant that would be compiled into the program
+        def core(post_contrib: jax.Array, sel: jax.Array, seg: jax.Array):
             flat = jax.ops.segment_sum(
-                self.post_contrib[sel], seg, num_segments=num_segments
+                post_contrib[sel], seg, num_segments=num_segments
             )
             scores = flat[: Q_BLOCK * n].reshape(Q_BLOCK, n)
             v, i = jax.lax.top_k(scores, k)
@@ -265,7 +267,7 @@ class BM25Index:
                     seg[off : off + c.size] = r * self.n_passages + self._post_doc_np[c]
                     off += c.size
             fn = self._search_fn(k, e_pad)
-            v, i = fn(jnp.asarray(sel), jnp.asarray(seg))
+            v, i = fn(self.post_contrib, jnp.asarray(sel), jnp.asarray(seg))
             rows = len(chunk)
             out_scores[s : s + rows] = np.asarray(v, np.float32)[:rows]
             out_ids[s : s + rows] = np.asarray(i, np.int32)[:rows]

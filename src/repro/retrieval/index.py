@@ -9,16 +9,17 @@ cosine similarity ("FAISS inner-product index", §V.E). Three search paths:
   a thousand — runs the same compiled program and nothing retraces per query.
   ``scorer`` selects the implementation:
 
-  - ``"blocked"`` (default): blocked matmul + running top-k
-    (``topk.blocked_topk``) — the CPU/GPU oracle path.
+  - ``"blocked"`` (default): block matmul (``topk.mips_scores``) +
+    running top-k (``topk.blocked_topk``) — the served scorer.
   - ``"pallas"``: the fused Pallas ``mips_topk`` TPU kernel
-    (``kernels.mips_topk``); the corpus is auto-padded to a block multiple
-    and pad rows are masked inside the kernel (``n_valid``). Pass
-    ``interpret=True`` to run it off-TPU.
+    (``kernels.mips_topk``). Pass ``interpret=True`` to run it off-TPU.
 
-  The fixed block shape is what makes the serving fast path's batched
-  retrieval *bit-identical* to per-query retrieval: a query row's scores
-  depend only on its own block row, never on which queries share the batch.
+  Both take the corpus as a program argument (never a compiled-in
+  constant), zero-padded to whole ``SCORE_BLOCK`` row blocks with the pad
+  rows masked. The fixed query block makes batched retrieval
+  *bit-identical* to per-query retrieval (a row's scores depend only on its
+  own block row), and the fixed corpus block makes it bit-identical across
+  shards (a score never depends on how many rows share the matmul).
 * :meth:`sharded_search_fn` — corpus rows sharded over mesh axes with
   ``shard_map``; per-shard local top-k then hierarchical merge
   (``topk.distributed_topk``). This is the production path and the
@@ -38,9 +39,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import SCORE_BLOCK
 from repro.retrieval.chunking import Passage
 from repro.retrieval.embedder import Embedder
-from repro.retrieval.topk import blocked_topk, distributed_topk
+from repro.retrieval.topk import blocked_topk, distributed_topk, mips_scores
 
 # Fixed query-block width for the compiled search closures. Every search is
 # padded to a multiple of this, so the compiled matmul shape — and therefore
@@ -68,14 +70,49 @@ def l2_normalize(x: jnp.ndarray, eps: float = 1e-9) -> jnp.ndarray:
     return x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), eps)
 
 
-def _pallas_block_width(n_rows: int, k: int) -> int:
-    """Corpus block width for the pallas scorer: lane-aligned, >= k, capped
-    for VMEM. Shared by the single-device path and the per-shard sharded
-    path so both pad corpora identically."""
-    bn = 128 if n_rows <= 2048 else 1024
+def _block_width(k: int) -> int:
+    """Rows a corpus pads to a multiple of: :data:`~repro.kernels.SCORE_BLOCK`,
+    doubled until it holds ``k`` (the Pallas kernel selects its top-k
+    within one block). Shared by both scorers, the single-device path and
+    the per-shard sharded path, so every corpus pads identically and both
+    scorers share one device copy."""
+    bn = SCORE_BLOCK
     while bn < k:
         bn *= 2
     return bn
+
+
+def search_program(
+    k: int, n_valid: int, scorer: str = "blocked", *, interpret: bool = False
+) -> Callable:
+    """The jit-compiled single-device search: ``(corpus, (Q_BLOCK, d)) →
+    (scores (Q_BLOCK, k), ids (Q_BLOCK, k))`` over a corpus zero-padded to
+    whole score blocks, whose rows past ``n_valid`` are never candidates.
+    ``scorer`` is ``"blocked"`` (block matmul + running top-k) or
+    ``"pallas"`` (the fused ``mips_topk`` kernel)."""
+    if scorer == "blocked":
+
+        def core(corpus: jax.Array, q: jax.Array):
+            scores = mips_scores(l2_normalize(q), corpus)  # (bq, n_padded)
+            if corpus.shape[0] != n_valid:  # pad rows are never candidates
+                col = jnp.arange(corpus.shape[0])[None, :]
+                scores = jnp.where(col < n_valid, scores, -jnp.inf)
+            return blocked_topk(scores, k)
+
+    elif scorer == "pallas":
+        from repro.kernels.mips_topk.kernel import mips_topk_pallas
+
+        bn = _block_width(k)
+
+        def core(corpus: jax.Array, q: jax.Array):
+            return mips_topk_pallas(
+                l2_normalize(q), corpus, k,
+                block_q=Q_BLOCK, block_n=bn, n_valid=n_valid, interpret=interpret,
+            )
+
+    else:
+        raise ValueError(f"unknown scorer {scorer!r}; expected one of {SCORERS}")
+    return jax.jit(core)
 
 
 class DenseIndex:
@@ -94,15 +131,25 @@ class DenseIndex:
         # another index's .embeddings — the ShardedBackend construction path).
         # Skipping the re-normalization matters for bit-exactness: dividing a
         # unit vector by its ~1.0 norm perturbs last-bit floats.
-        emb = jnp.asarray(embeddings, jnp.float32)
-        self.embeddings = emb if assume_normalized else l2_normalize(emb)
+        #
+        # Normalized host (numpy) rows stay on the host: a single-device
+        # search places them on the default device when it first needs them
+        # (_device_corpus), and the device-sharded backend places each shard
+        # straight onto its own device, so a corpus larger than one chip is
+        # never staged whole on the first.
+        if assume_normalized and isinstance(embeddings, np.ndarray):
+            self.embeddings = np.asarray(embeddings, np.float32)
+        else:
+            emb = jnp.asarray(embeddings, jnp.float32)
+            self.embeddings = emb if assume_normalized else l2_normalize(emb)
         self.passages = list(passages) if passages is not None else None
         if self.passages is not None and len(self.passages) != embeddings.shape[0]:
             raise ValueError("passages/embeddings length mismatch")
-        # (k, scorer, interpret) → jit-compiled fixed-shape search closure
-        self._fn_cache: dict[tuple, Callable] = {}
-        # block_n → corpus zero-padded to a block_n multiple (pallas path)
-        self._padded_corpus: dict[int, jnp.ndarray] = {}
+        # (k, scorer, interpret) → (jit-compiled fixed-shape search program,
+        # the device corpus it takes as its first argument)
+        self._fn_cache: dict[tuple, tuple[Callable, jax.Array]] = {}
+        # block width → device corpus zero-padded to a multiple of it
+        self._padded_corpus: dict[int, jax.Array] = {}
 
     # -- construction ---------------------------------------------------------
     @classmethod
@@ -126,55 +173,35 @@ class DenseIndex:
         return self.embeddings.shape[1]
 
     # -- single-device search ---------------------------------------------------
-    def _pallas_block_n(self, k: int) -> int:
-        """Corpus block width: lane-aligned, >= k, capped for VMEM."""
-        return _pallas_block_width(self.size, k)
-
-    def _pallas_corpus(self, bn: int) -> jnp.ndarray:
+    def _device_corpus(self, bn: int) -> jax.Array:
+        """The corpus zero-padded to a multiple of ``bn`` rows, on the
+        default device, placed on first use. Host rows are padded on the
+        host, so the device only ever holds the padded copy."""
         corpus = self._padded_corpus.get(bn)
         if corpus is None:
             pad = (-self.size) % bn
-            corpus = self.embeddings
-            if pad:
-                corpus = jnp.concatenate(
-                    [corpus, jnp.zeros((pad, self.dim), jnp.float32)], axis=0
-                )
-            self._padded_corpus[bn] = corpus
+            emb = self.embeddings
+            if pad and isinstance(emb, np.ndarray):
+                emb = np.concatenate([emb, np.zeros((pad, self.dim), np.float32)])
+            elif pad:
+                emb = jnp.concatenate([emb, jnp.zeros((pad, self.dim), jnp.float32)])
+            corpus = self._padded_corpus[bn] = jnp.asarray(emb)
         return corpus
 
-    def _search_fn(self, k: int, scorer: str, interpret: bool) -> Callable:
-        """Cached jit-compiled ``(Q_BLOCK, d) → ((Q_BLOCK, k), (Q_BLOCK, k))``
-        search closure — compiled once per (k, scorer), reused by every
-        subsequent query/batch so the serving hot path never retraces."""
+    def _search_fn(self, k: int, scorer: str, interpret: bool) -> tuple[Callable, jax.Array]:
+        """Cached jit-compiled ``(corpus, (Q_BLOCK, d)) → ((Q_BLOCK, k),
+        (Q_BLOCK, k))`` search program and the device corpus it takes —
+        compiled once per (k, scorer), reused by every subsequent
+        query/batch so the serving hot path never retraces. The corpus is an
+        argument, never a captured constant: a constant would be compiled
+        into the program (past the 2 GB serialized-program limit at 10⁶×768
+        f32) and held on the device a second time."""
         key = (k, scorer, interpret)
-        fn = self._fn_cache.get(key)
-        if fn is not None:
-            return fn
-        if scorer == "blocked":
-            emb_t = self.embeddings.T
-
-            def core(q: jnp.ndarray):
-                scores = l2_normalize(q) @ emb_t  # (bq, n)
-                return blocked_topk(scores, k)
-
-        elif scorer == "pallas":
-            from repro.kernels.mips_topk.kernel import mips_topk_pallas
-
-            bn = self._pallas_block_n(k)
-            corpus = self._pallas_corpus(bn)
-            n_valid = self.size
-
-            def core(q: jnp.ndarray):
-                return mips_topk_pallas(
-                    l2_normalize(q), corpus, k,
-                    block_q=Q_BLOCK, block_n=bn, n_valid=n_valid, interpret=interpret,
-                )
-
-        else:
-            raise ValueError(f"unknown scorer {scorer!r}; expected one of {SCORERS}")
-        fn = jax.jit(core)
-        self._fn_cache[key] = fn
-        return fn
+        entry = self._fn_cache.get(key)
+        if entry is None:
+            fn = search_program(k, self.size, scorer, interpret=interpret)
+            entry = self._fn_cache[key] = (fn, self._device_corpus(_block_width(k)))
+        return entry
 
     def search_batch(
         self,
@@ -197,14 +224,14 @@ class DenseIndex:
         nq = query_vecs.shape[0]
         if nq == 0:
             return jnp.zeros((0, k), jnp.float32), jnp.zeros((0, k), jnp.int32)
-        fn = self._search_fn(k, scorer, interpret)
+        fn, corpus = self._search_fn(k, scorer, interpret)
         pad = (-nq) % Q_BLOCK
         if isinstance(query_vecs, jax.core.Tracer):
             # traced (inside a caller's jit): stay pure-jnp
             q = jnp.asarray(query_vecs, jnp.float32)
             if pad:
                 q = jnp.concatenate([q, jnp.zeros((pad, q.shape[1]), jnp.float32)], axis=0)
-            outs = [fn(q[s : s + Q_BLOCK]) for s in range(0, q.shape[0], Q_BLOCK)]
+            outs = [fn(corpus, q[s : s + Q_BLOCK]) for s in range(0, q.shape[0], Q_BLOCK)]
             vals = jnp.concatenate([v for v, _ in outs], axis=0)[:nq]
             ids = jnp.concatenate([i for _, i in outs], axis=0)[:nq]
             return vals, ids
@@ -215,7 +242,7 @@ class DenseIndex:
             q = np.concatenate([q, np.zeros((pad, q.shape[1]), np.float32)], axis=0)
         vals_np, ids_np = [], []
         for s in range(0, q.shape[0], Q_BLOCK):
-            v, i = fn(jnp.asarray(q[s : s + Q_BLOCK]))
+            v, i = fn(corpus, jnp.asarray(q[s : s + Q_BLOCK]))
             vals_np.append(np.asarray(v, np.float32))
             ids_np.append(np.asarray(i, np.int32))
         vals = np.concatenate(vals_np, axis=0)[:nq] if len(vals_np) > 1 else vals_np[0][:nq]
@@ -252,7 +279,6 @@ class DenseIndex:
         scorer: str = "blocked",
         interpret: bool = False,
         n_valid: int | None = None,
-        block_n: int | None = None,
     ):
         """Build a shard_map'd exact search over corpus rows.
 
@@ -271,9 +297,9 @@ class DenseIndex:
         ``lax.axis_index``) before the local top-k, so padded rows can never
         enter the candidate set. Requires ``k <= n_valid`` (callers clamp,
         exactly as :meth:`search_batch` clamps k to the corpus size). For
-        ``scorer="pallas"``, per-shard rows must additionally divide
-        ``block_n`` (defaults to the same heuristic as the single-device
-        pallas path).
+        ``scorer="pallas"``, per-shard rows must additionally be a multiple
+        of the score block width (``_block_width(k)``, as the single-device
+        path pads).
         """
         from jax.sharding import PartitionSpec as P
 
@@ -293,7 +319,7 @@ class DenseIndex:
             queries = l2_normalize(queries)  # cosine, matching search_batch
             kk = min(k, rows)
             if scorer == "pallas":
-                bn = block_n if block_n is not None else _pallas_block_width(rows, kk)
+                bn = _block_width(kk)
                 mask = None
                 if n_valid is not None:
                     # traced per-shard residue mask: real global row < n_valid
@@ -304,7 +330,7 @@ class DenseIndex:
                     valid_mask=mask, interpret=interpret,
                 )
             else:
-                scores = queries @ corpus_shard.T  # (nq, rows_local)
+                scores = mips_scores(queries, corpus_shard)  # (nq, rows_local)
                 if n_valid is not None:
                     col = start + jnp.arange(rows)[None, :]
                     scores = jnp.where(col < n_valid, scores, -jnp.inf)
@@ -314,10 +340,8 @@ class DenseIndex:
                 v, i = distributed_topk(v, i, k, ax)
             return v, i
 
-        from repro.distributed import shard_map_compat
-
         return jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 local_search,
                 mesh=mesh,
                 in_specs=(corpus_spec, P(None, None)),

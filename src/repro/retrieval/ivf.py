@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import SCORE_PRECISION
 from repro.retrieval.index import l2_normalize
 
 
@@ -138,9 +139,11 @@ class IVFIndex:
 
     # -- cached search closures ------------------------------------------------
     def _search_fn(self, k: int, n_probe: int, impl: str = "bag"):
-        """Cached jit-compiled fixed-shape ``(Q_BLOCK, d)`` probe+score
-        closure — one compiled program per (impl, k, n_probe), like
-        ``DenseIndex._search_fn``. The fixed block shape is what makes a
+        """Cached ``(program, arrays)``: a jit-compiled fixed-shape
+        ``(*arrays, (Q_BLOCK, d))`` probe+score program — one per (impl, k,
+        n_probe), like ``DenseIndex._search_fn`` — and the index arrays it
+        takes as arguments (never captured constants, which would be
+        compiled into the program). The fixed block shape is what makes a
         query row's scores independent of the caller's batch size: XLA may
         tile a shape-(nq, d) matmul differently per nq, which perturbs the
         last float bits — enough to break the serving pipeline's bit-exact
@@ -158,13 +161,19 @@ class IVFIndex:
 
         if impl == "padded":
 
-            def core(q: jnp.ndarray):  # (Q_BLOCK, d) raw; normalized in-closure
-                q = l2_normalize(q)
-                _, probe = jax.lax.top_k(q @ self.centroids.T, n_probe)  # (bq, p)
-                cand_ids = self.buckets[probe].reshape(q.shape[0], -1)  # (bq, p*cap)
-                cand_mask = self.bucket_mask[probe].reshape(q.shape[0], -1)
-                cand_vecs = self.embeddings[jnp.maximum(cand_ids, 0)]  # (bq, m, d)
-                scores = jnp.einsum("qd,qmd->qm", q, cand_vecs)
+            arrays = (self.centroids, self.buckets, self.bucket_mask, self.embeddings)
+
+            def core(centroids, buckets, bucket_mask, embeddings, q):
+                q = l2_normalize(q)  # (Q_BLOCK, d) raw; normalized here
+                _, probe = jax.lax.top_k(
+                    jnp.matmul(q, centroids.T, precision=SCORE_PRECISION), n_probe
+                )  # (bq, p)
+                cand_ids = buckets[probe].reshape(q.shape[0], -1)  # (bq, p*cap)
+                cand_mask = bucket_mask[probe].reshape(q.shape[0], -1)
+                cand_vecs = embeddings[jnp.maximum(cand_ids, 0)]  # (bq, m, d)
+                scores = jnp.einsum(
+                    "qd,qmd->qm", q, cand_vecs, precision=SCORE_PRECISION
+                )
                 scores = jnp.where(cand_mask, scores, -jnp.inf)
                 v, sel = jax.lax.top_k(scores, k_eff)
                 ids = jnp.take_along_axis(cand_ids, sel, axis=-1)
@@ -173,10 +182,13 @@ class IVFIndex:
         elif impl == "bag":
             members, member_embs, starts, lens, _ = self._bag()
             w = self._bag_width(n_probe)
+            arrays = (self.centroids, members, member_embs, starts, lens)
 
-            def core(q: jnp.ndarray):  # (Q_BLOCK, d) raw; normalized in-closure
-                q = l2_normalize(q)
-                _, probe = jax.lax.top_k(q @ self.centroids.T, n_probe)  # (bq, p)
+            def core(centroids, members, member_embs, starts, lens, q):
+                q = l2_normalize(q)  # (Q_BLOCK, d) raw; normalized here
+                _, probe = jax.lax.top_k(
+                    jnp.matmul(q, centroids.T, precision=SCORE_PRECISION), n_probe
+                )  # (bq, p)
                 lens_p = lens[probe]  # (bq, p)
                 ends = jnp.cumsum(lens_p, axis=1)
                 j = jnp.arange(w, dtype=jnp.int32)[None, :]  # (1, w)
@@ -189,7 +201,9 @@ class IVFIndex:
                 probe_sel = jnp.take_along_axis(probe, segc, axis=1)  # (bq, w)
                 local = j - jnp.take_along_axis(begins, segc, axis=1)
                 midx = jnp.where(valid, starts[probe_sel] + local, 0)
-                scores = jnp.einsum("qd,qwd->qw", q, member_embs[midx])
+                scores = jnp.einsum(
+                    "qd,qwd->qw", q, member_embs[midx], precision=SCORE_PRECISION
+                )
                 scores = jnp.where(valid, scores, -jnp.inf)
                 ids = jnp.where(valid, members[midx], -1)
                 if w < k_eff:  # tiny posting mass: pad up to the contract width
@@ -209,8 +223,8 @@ class IVFIndex:
         else:
             raise ValueError(f"unknown ivf impl {impl!r}; expected 'bag' or 'padded'")
 
-        fn = cache[key] = jax.jit(core)
-        return fn
+        entry = cache[key] = (jax.jit(core), arrays)
+        return entry
 
     def search_batch(
         self,
@@ -237,13 +251,13 @@ class IVFIndex:
         k_eff = min(k, n_probe * cap)
         if nq == 0:
             return jnp.zeros((0, k_eff), jnp.float32), jnp.zeros((0, k_eff), jnp.int32)
-        fn = self._search_fn(k, n_probe, impl)
+        fn, arrays = self._search_fn(k, n_probe, impl)
         pad = (-nq) % Q_BLOCK
         if pad:
             q = np.concatenate([q, np.zeros((pad, q.shape[1]), np.float32)], axis=0)
         vals, ids = [], []
         for s in range(0, q.shape[0], Q_BLOCK):
-            v, i = fn(jnp.asarray(q[s : s + Q_BLOCK]))
+            v, i = fn(*arrays, jnp.asarray(q[s : s + Q_BLOCK]))
             vals.append(np.asarray(v, np.float32))
             ids.append(np.asarray(i, np.int32))
         v_np = np.concatenate(vals, axis=0)[:nq] if len(vals) > 1 else vals[0][:nq]

@@ -85,11 +85,13 @@ from repro.retrieval.backend import (
     RetrievalBackend,
 )
 from repro.retrieval.chunking import Passage
-from repro.retrieval.index import Q_BLOCK, DenseIndex, _pallas_block_width
+from repro.retrieval.index import Q_BLOCK, DenseIndex, _block_width
 from repro.retrieval.topk import merge_topk
+from repro.runtime import accelerator_attached, refuse_children_on_accelerator
 
 # "auto" resolves at construction time (resolve_execution): inline host
-# fan-out on single-core hosts, process workers when real cores exist.
+# fan-out on single-core hosts or an accelerator, process workers when real
+# CPU cores do the searching.
 EXECUTIONS = ("threads", "process", "device", "auto")
 
 
@@ -101,14 +103,17 @@ def resolve_execution(execution: str, *, n_shards: int, workers: int = 0) -> str
     S=4 collapse the serving bench exposed) — so auto never picks a thread
     pool: single shard or single core → ``"threads"`` with the serial
     inline fan-out (no pool, no handoff); multi-core and S > 1 →
-    ``"process"`` (one spawned worker per shard, GIL-free). An explicit
-    ``workers`` request is honored as the thread pool the caller asked for.
+    ``"process"`` (one spawned worker per shard, GIL-free) — but only when
+    the default backend is the CPU: on an accelerator this process holds
+    the chip and the workers could not reach it, so auto stays inline. An
+    explicit ``workers`` request is honored as the thread pool the caller
+    asked for.
     """
     if execution != "auto":
         return execution
     if workers:
         return "threads"
-    if n_shards > 1 and (os.cpu_count() or 1) > 1:
+    if n_shards > 1 and (os.cpu_count() or 1) > 1 and not accelerator_attached():
         return "process"
     return "threads"
 
@@ -559,29 +564,41 @@ class DeviceShardedBackend(ShardedBackend):
 
     # -- device program construction ------------------------------------------
     def _rows_per_shard(self, k: int) -> int:
-        rows = math.ceil(self.size / self._n_shards)
-        if self.scorer == "pallas":
-            bn = _pallas_block_width(rows, k)
-            rows = math.ceil(rows / bn) * bn
-        return rows
+        """Rows each shard holds: its share of the corpus, padded to whole
+        score blocks so every shard scores exactly as the unsharded index."""
+        bn = _block_width(k)
+        return math.ceil(math.ceil(self.size / self._n_shards) / bn) * bn
 
-    def _placed_corpus(self, rows_per: int) -> jnp.ndarray:
+    def _placed_corpus(self, rows_per: int) -> jax.Array:
+        """The corpus zero-padded to ``rows_per`` rows per shard, each shard
+        placed straight from the host onto its own device: no device ever
+        holds more than its shard, so a corpus larger than one chip never
+        stages whole on the first."""
         corpus = self._corpus_cache.get(rows_per)
         if corpus is None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+            from jax.sharding import NamedSharding
 
             from repro.distributed.partition import ShardingPolicy
 
             # the mesh_layout() corpus spec, parameterized by this mesh's
             # actual axis names (a custom mesh may not call its axis "data")
             corpus_spec, _, _ = mesh_layout(ShardingPolicy(data_axes=self.shard_axes))
-            padded = rows_per * self._n_shards
-            emb = self.index.embeddings
-            if padded != self.size:
-                emb = jnp.concatenate(
-                    [emb, jnp.zeros((padded - self.size, self.index.dim), jnp.float32)]
-                )
-            corpus = jax.device_put(emb, NamedSharding(self.mesh, corpus_spec))
+            host = np.asarray(self.index.embeddings, np.float32)
+            n, d = host.shape
+
+            def shard_rows(index: tuple[slice, ...]) -> np.ndarray:
+                start, stop, _ = index[0].indices(rows_per * self._n_shards)
+                rows = host[min(start, n) : min(stop, n)]
+                if rows.shape[0] < stop - start:  # the padded tail shard(s)
+                    fill = np.zeros((stop - start - rows.shape[0], d), np.float32)
+                    rows = np.concatenate([rows, fill])
+                return rows
+
+            corpus = jax.make_array_from_callback(
+                (rows_per * self._n_shards, d),
+                NamedSharding(self.mesh, corpus_spec),
+                shard_rows,
+            )
             self._corpus_cache[rows_per] = corpus
         return corpus
 
@@ -593,7 +610,6 @@ class DeviceShardedBackend(ShardedBackend):
             return entry
         rows_per = self._rows_per_shard(k)
         padded = rows_per * self._n_shards
-        block_n = _pallas_block_width(rows_per, k) if self.scorer == "pallas" else None
         fn, _ = self.index.sharded_search_fn(
             self.mesh,
             k,
@@ -601,7 +617,6 @@ class DeviceShardedBackend(ShardedBackend):
             scorer=self.scorer,
             interpret=self.interpret,
             n_valid=self.size if padded != self.size else None,
-            block_n=block_n,
         )
         entry = (fn, self._placed_corpus(rows_per))
         self._fn_cache[k] = entry
@@ -711,6 +726,10 @@ class ProcessShardedBackend(ShardedBackend):
     — and the :class:`ShardCounters` discipline (S ``shard_searches`` and
     S-1 ``merges`` per call) — are bit-identical to it.
 
+    Construction raises :class:`~repro.runtime.AcceleratorHeldError` when
+    the default backend is an accelerator: this process then holds the
+    chip, and workers that build jax state could not reach it.
+
     Workers spawn lazily on the first search (``spawn`` context: the
     parent's jax runtime threads make fork unsafe) and each pays one jax
     import + index build; :meth:`warm` fronts that cost. Passage payloads
@@ -733,6 +752,7 @@ class ProcessShardedBackend(ShardedBackend):
         name: str | None = None,
         cost: BackendCost | None = None,
     ):
+        refuse_children_on_accelerator("shard_execution='process'")
         # shard_bounds is the one validator of (n, S) combinations; calling
         # it here keeps process-path errors identical to the threads path.
         self.bounds = shard_bounds(index.size, n_shards)

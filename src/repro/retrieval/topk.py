@@ -12,6 +12,40 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import SCORE_BLOCK, SCORE_PRECISION
+
+
+def mips_scores(queries: jnp.ndarray, corpus: jnp.ndarray) -> jnp.ndarray:
+    """``(nq, d) × (n, d) → (nq, n)`` inner products, block by block.
+
+    Each block of :data:`~repro.kernels.SCORE_BLOCK` corpus rows is one
+    matmul of a fixed shape, batched over blocks at
+    :data:`~repro.kernels.SCORE_PRECISION`, so a score is the same float
+    whether its row is scored in the whole corpus, in a shard, or in the
+    Pallas kernel's block. The corpus's last axis is contracted in place (no
+    transposed copy). Callers on the hot path pass corpora padded to a
+    block multiple; a ragged tail is scored as one zero-padded block.
+    """
+    nq, d = queries.shape
+    n = corpus.shape[0]
+    nb, rem = divmod(n, SCORE_BLOCK)
+    parts = []
+    if nb:
+        full = corpus if not rem else corpus[: nb * SCORE_BLOCK]
+        blocks = full.reshape(nb, SCORE_BLOCK, d)
+        qb = jnp.broadcast_to(queries, (nb, nq, d))
+        s = jax.lax.dot_general(
+            qb, blocks, (((2,), (2,)), ((0,), (0,))), precision=SCORE_PRECISION
+        )  # (nb, nq, SCORE_BLOCK)
+        parts.append(jnp.moveaxis(s, 0, 1).reshape(nq, nb * SCORE_BLOCK))
+    if rem:
+        tail = jnp.pad(corpus[nb * SCORE_BLOCK :], ((0, SCORE_BLOCK - rem), (0, 0)))
+        s = jax.lax.dot_general(
+            queries, tail, (((1,), (1,)), ((), ())), precision=SCORE_PRECISION
+        )
+        parts.append(s[:, :rem])
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
 
 def blocked_topk(scores: jnp.ndarray, k: int, *, block: int = 4096) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Top-k over the last axis without materializing a full sort.

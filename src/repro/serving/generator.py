@@ -233,7 +233,9 @@ class TransformerSlotDecoder:
         self.steps_run = 0
         max_len = cfg.max_seq_len
 
-        def step(cache, toks):
+        # params are an argument, never captured constants that would be
+        # compiled into the program (and held on the device twice)
+        def step(params, cache, toks):
             # wrap slots that hit the context window (inert restart; the
             # scheduler's token budget, not the cache, bounds generation)
             cache = dataclasses.replace(
@@ -268,7 +270,7 @@ class TransformerSlotDecoder:
         state — benchmarks call this so compile cost lands nowhere."""
         import jax
 
-        jax.block_until_ready(self._step(self.cache, self.tokens)[0])
+        jax.block_until_ready(self._step(self.params, self.cache, self.tokens)[0])
 
     def reset(self) -> None:
         """Forget all slot assignments (between independent runs request_ids
@@ -316,7 +318,7 @@ class TransformerSlotDecoder:
                         "the scheduler's max_batch_slots"
                     )
                 self._assign(req)
-        self.tokens, self.cache = self._step(self.cache, self.tokens)
+        self.tokens, self.cache = self._step(self.params, self.cache, self.tokens)
         self.steps_run += 1
         if self.eos_id is None:
             return [False] * len(active)

@@ -32,6 +32,10 @@ Spawn (never fork) is mandatory: the parent holds jax runtime threads and
 jit caches that do not survive a fork. A spawned worker re-imports the
 code, pays one engine build (~1 s on the paper corpus), and amortizes it
 over every micro-batch it drains.
+
+On an accelerator the parent holds the chip, and a worker that builds jax
+state could not reach it: :class:`ProcessStageExecutor` then refuses with
+:class:`~repro.runtime.AcceleratorHeldError` before spawning anything.
 """
 
 from __future__ import annotations
@@ -42,6 +46,8 @@ import pickle
 from concurrent.futures import Future, ProcessPoolExecutor
 from multiprocessing import get_context
 from typing import TYPE_CHECKING, Callable
+
+from repro.runtime import refuse_children_on_accelerator
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard
     from repro.retrieval.stack import BackendStackConfig
@@ -171,6 +177,7 @@ class ProcessStageExecutor:
         max_workers: int = 1,
         mp_context: str = "spawn",
     ):
+        refuse_children_on_accelerator("executor='process'")
         self._factory_bytes = ensure_picklable(engine_factory, "engine factory")
         self.max_workers = max(1, int(max_workers))
         self._pool = ProcessPoolExecutor(

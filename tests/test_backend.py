@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 
 from repro.core.bundles import Bundle, BundleCatalog, DEFAULT_CATALOG, make_catalog
 from repro.core.policies import make_policy

@@ -16,9 +16,8 @@ batch shapes, score ties, ``k >= corpus``, and empty/no-match queries,
 because the serving layer's exact-replay parity (drained streaming ≡
 ``answer_batch``) is built on rows never moving by a single ulp.
 
-Deterministic seeded sweeps always run; hypothesis fuzzing of the same
-invariants engages when the package is installed (skips otherwise via
-``_hypothesis_compat``).
+Deterministic seeded sweeps pin known cases; hypothesis fuzzes the same
+invariants.
 """
 
 import numpy as np
@@ -26,7 +25,8 @@ import pytest
 
 import jax.numpy as jnp
 
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 
 from repro.core.bundles import make_catalog
 from repro.core.policies import make_policy

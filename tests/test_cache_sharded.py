@@ -22,7 +22,8 @@ import pytest
 
 import jax.numpy as jnp
 
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 
 from repro.core.bundles import Bundle, BundleCatalog, make_catalog
 from repro.core.guardrails import GuardrailConfig, Guardrails

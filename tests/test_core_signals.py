@@ -1,6 +1,7 @@
 """Unit + property tests for query signals and complexity (paper §V.A)."""
 
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 import jax.numpy as jnp
 import numpy as np
 import pytest

@@ -1,6 +1,7 @@
 """Tests for Eq. 1 utilities, routing, policies and guardrails."""
 
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -25,10 +25,8 @@ def test_collective_parser_counts_psum():
     def f(x):
         return jax.lax.psum(x, "data")
 
-    from repro.distributed import shard_map_compat
-
     fn = jax.jit(
-        shard_map_compat(f, mesh=mesh, in_specs=P(None), out_specs=P(None), check_vma=False)
+        jax.shard_map(f, mesh=mesh, in_specs=P(None), out_specs=P(None), check_vma=False)
     )
     compiled = fn.lower(jax.ShapeDtypeStruct((1024,), jnp.float32)).compile()
     coll = collective_bytes_from_hlo(compiled.as_text())
@@ -108,7 +106,7 @@ def test_reduced_lm_cell_lowers_and_compiles():
 
 def test_mesh_function_does_not_touch_devices_on_import():
     """make_production_mesh must be a function, not module state."""
-    import repro.launch.mesh as m
+    import repro.distributed.mesh_utils as m
 
     assert callable(m.make_production_mesh)
     assert not any(

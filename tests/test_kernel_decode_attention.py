@@ -91,10 +91,8 @@ def test_distributed_flash_decode_matches_single_device():
     body = lambda q, k, v, lens: decode_attention_sharded_body(
         q, k, v, lens, axis_name="model"
     )
-    from repro.distributed import shard_map_compat
-
     fn = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(), P(None, "model", None, None), P(None, "model", None, None), P()),

@@ -1,6 +1,7 @@
 """EmbeddingBag kernel vs oracle: sweeps, unsorted input, empty bags."""
 
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np

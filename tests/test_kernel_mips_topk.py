@@ -4,7 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 
 from repro.kernels.mips_topk.kernel import mips_topk_pallas
 from repro.kernels.mips_topk.ops import mips_topk

@@ -67,8 +67,7 @@ def test_distributed_flash_decode_8_way_sp():
         v = jax.random.normal(ks[2], (b, s, hk, dh))
         lengths = jax.random.randint(ks[3], (b,), 1, s + 1)
         mesh = make_mesh((8,), ("model",))
-        from repro.distributed import shard_map_compat
-        fn = jax.jit(shard_map_compat(
+        fn = jax.jit(jax.shard_map(
             lambda q, k, v, l: decode_attention_sharded_body(q, k, v, l, axis_name="model"),
             mesh=mesh,
             in_specs=(P(), P(None, "model", None, None), P(None, "model", None, None), P()),

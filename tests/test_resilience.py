@@ -9,7 +9,7 @@ Pins the PR's contracts:
 2. **Bounded, seeded resilience** — retries never exceed the policy bound,
    backoff sequences are reproducible under a fixed seed, and the circuit
    breaker's closed/open/half-open machine honours cooldown and probe
-   quotas (hypothesis-fuzzed where available, deterministic otherwise).
+   quotas (hypothesis-fuzzed, plus deterministic cases).
 3. **Zero-fault parity** — wrapping healthy backends in the full
    fault+cache+resilience decorator stack changes nothing: byte-identical
    telemetry CSVs on the paper and extended catalogs, bit-identical drained
@@ -31,7 +31,8 @@ import pytest
 
 import jax.numpy as jnp
 
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 
 from repro.core.bundles import make_catalog
 from repro.core.policies import make_policy

@@ -5,7 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 
 from repro.data import BENCHMARK_CORPUS, BENCHMARK_QUERIES, corpus_document
 from repro.retrieval import (

@@ -1,6 +1,7 @@
 """Hypothesis property tests for the continuous-batching scheduler."""
 
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 
 from repro.serving.scheduler import ContinuousBatchScheduler, Request, SchedulerConfig
 

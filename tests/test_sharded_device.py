@@ -28,7 +28,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 
 from repro.distributed import corpus_mesh
 from repro.retrieval import (
@@ -250,8 +251,7 @@ def test_device_identity_sweep_4_devices():
 @pytest.mark.slow
 def test_device_identity_property_under_4_devices():
     """Run the in-file hypothesis property test where it does not skip: a
-    pytest subprocess with 4 forced host devices. Skips (cleanly) inside the
-    subprocess too when hypothesis is absent from the environment."""
+    pytest subprocess with 4 forced host devices."""
     proc = subprocess.run(
         [sys.executable, "-m", "pytest", "-q",
          "tests/test_sharded_device.py::test_device_identity_property",

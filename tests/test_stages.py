@@ -22,7 +22,8 @@ import time
 import numpy as np
 import pytest
 
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 
 from repro.core.policies import make_policy
 from repro.data.benchmark import BENCHMARK_QUERIES, REFERENCE_ANSWERS

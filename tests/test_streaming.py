@@ -5,9 +5,8 @@ The tentpole contract: a drained StreamingEngine run over the paper
 benchmark produces the same per-query records as one ``answer_batch`` call
 over the arrival-ordered stream (chunking a stream through consecutive
 ``answer_batch`` calls never changes records — the consecutive-batches
-parity the batched tests already pin). Property tests (hypothesis, optional)
-fuzz arrival traces; deterministic seeded variants of the same invariants
-run even without hypothesis.
+parity the batched tests already pin). Property tests (hypothesis) fuzz
+arrival traces; deterministic seeded variants pin the same invariants.
 """
 
 import math
@@ -15,7 +14,8 @@ import math
 import numpy as np
 import pytest
 
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 
 from repro.core.policies import make_policy
 from repro.data.benchmark import BENCHMARK_QUERIES, REFERENCE_ANSWERS

@@ -1,0 +1,100 @@
+"""Compile the served retrieval programs for a TPU v5e, at real widths.
+
+Nothing here runs on a chip: the v5e:2x2 topology is *described* and the
+TPU compiler refuses what the chip would refuse — a kernel block that
+breaks the tiling rule, a program past the device's memory. Every test
+compiles in this process (a child could not load the TPU library while
+this process holds it), and the topology is described inside a fixture,
+never at import, so every test worker collects the same tests.
+"""
+
+from __future__ import annotations
+
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from repro.kernels import SCORE_BLOCK
+from repro.kernels.mips_topk.kernel import mips_topk_pallas
+from repro.retrieval.index import Q_BLOCK, DenseIndex, search_program
+
+N_DOCS, DIM = 1_000_000, 768  # a million BERT-base-width passages, f32
+N_PADDED = math.ceil(N_DOCS / SCORE_BLOCK) * SCORE_BLOCK
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache out of it
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means: cannot describe here
+        jax.config.update("jax_enable_compilation_cache", was_enabled)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("k", [3, 5, 10])
+@pytest.mark.parametrize("masked", [False, True], ids=["n_valid", "valid_mask"])
+def test_mips_topk_compiles_for_v5e(one_chip, k, masked):
+    q = jax.ShapeDtypeStruct((Q_BLOCK, DIM), jnp.float32, sharding=one_chip)
+    c = jax.ShapeDtypeStruct((N_PADDED, DIM), jnp.float32, sharding=one_chip)
+    if masked:
+        m = jax.ShapeDtypeStruct((N_PADDED,), jnp.float32, sharding=one_chip)
+        fn = jax.jit(lambda q, c, m: mips_topk_pallas(q, c, k, block_n=SCORE_BLOCK, valid_mask=m))
+        compiled = fn.lower(q, c, m).compile()
+    else:
+        fn = jax.jit(lambda q, c: mips_topk_pallas(q, c, k, block_n=SCORE_BLOCK, n_valid=N_DOCS))
+        compiled = fn.lower(q, c).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the kernel, not a fallback
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= N_PADDED * DIM * 4
+
+
+@pytest.mark.parametrize("scorer", ["blocked", "pallas"])
+def test_dense_search_program_takes_the_corpus_as_an_argument(one_chip, scorer):
+    q = jax.ShapeDtypeStruct((Q_BLOCK, DIM), jnp.float32, sharding=one_chip)
+    c = jax.ShapeDtypeStruct((N_PADDED, DIM), jnp.float32, sharding=one_chip)
+    lowered = search_program(10, N_DOCS, scorer).lower(c, q)
+    assert len(lowered.as_text()) < 200_000  # no corpus-sized constant
+    mem = lowered.compile().memory_analysis()
+    corpus_bytes = N_PADDED * DIM * 4
+    assert corpus_bytes <= mem.argument_size_in_bytes < corpus_bytes * 1.01
+    # the program holds no second copy of the corpus
+    assert mem.temp_size_in_bytes < corpus_bytes / 10
+
+
+def test_four_chip_sharded_search_puts_a_quarter_on_each_device(topo):
+    n_docs, shards = 6_000_000, 4
+    rows_per = math.ceil(n_docs / shards / SCORE_BLOCK) * SCORE_BLOCK
+    mesh = Mesh(np.asarray(topo.devices[:shards]), ("data",))
+    index = DenseIndex(np.zeros((8, DIM), np.float32), assume_normalized=True)
+    fn, n = index.sharded_search_fn(mesh, 10, ("data",), n_valid=n_docs)
+    assert n == shards
+    c = jax.ShapeDtypeStruct(
+        (rows_per * shards, DIM), jnp.float32, sharding=NamedSharding(mesh, P("data", None))
+    )
+    q = jax.ShapeDtypeStruct((Q_BLOCK, DIM), jnp.float32, sharding=NamedSharding(mesh, P()))
+    compiled = fn.lower(c, q).compile()
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    quarter = n_docs * DIM * 4 / shards
+    assert quarter <= per_device < 1.01 * quarter
+    assert "all-gather" in compiled.as_text()  # the on-device top-k merge

@@ -3,7 +3,8 @@ fault tolerance, data pipeline."""
 
 import os
 
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np

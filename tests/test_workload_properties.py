@@ -1,14 +1,14 @@
 """Property tests for arrival workloads (zipfian_indices / ArrivalProcess).
 
-Hypothesis-gated via the `_hypothesis_compat` shim: on containers without
-hypothesis the `@given` tests skip; the fixed-seed example tests always
-run, so the core contracts stay covered everywhere.
+Hypothesis fuzzes the contracts; the fixed-seed example tests pin them at
+known points.
 """
 
 import numpy as np
 import pytest
 
-from _hypothesis_compat import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 from repro.serving.workload import ArrivalProcess, zipfian_indices
 
 given = hypothesis.given
