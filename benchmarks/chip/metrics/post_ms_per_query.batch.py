@@ -1,0 +1,7 @@
+"""Self time of assemble, decode and finalize, ms per routed query (batch cells)."""
+
+from chipbench.readers import self_ms_per_query
+
+
+def read(run):
+    return self_ms_per_query(run, ("assemble", "decode", "finalize"), "routed")
