@@ -392,6 +392,13 @@ def main() -> None:
     telemetry = engine.telemetry if args.stream else engine.run(queries, references)
     telemetry.to_csv(args.out)
     print(telemetry.summary_json())
+    if not args.stream:
+        # what answer_batch served: replays are re-executions whose refined
+        # route flipped, each one more full search of the index
+        counts = engine.counts
+        print(f"served: {counts.routed} routed, {counts.replayed} replayed "
+              f"({100 * counts.replayed / max(1, counts.routed):.2f}%), "
+              f"searches by backend {counts.search_calls_by_backend}")
     if args.catalog != "paper":
         # (backend × depth) routing view: which retrieval method served what
         print(f"routed by backend: {catalog.routed_by_backend(telemetry.strategy_counts())}")

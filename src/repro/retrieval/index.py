@@ -38,6 +38,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels import SCORE_BLOCK
 from repro.retrieval.chunking import Passage
@@ -93,11 +94,13 @@ def search_program(
     if scorer == "blocked":
 
         def core(corpus: jax.Array, q: jax.Array):
-            scores = mips_scores(l2_normalize(q), corpus)  # (bq, n_padded)
-            if corpus.shape[0] != n_valid:  # pad rows are never candidates
-                col = jnp.arange(corpus.shape[0])[None, :]
-                scores = jnp.where(col < n_valid, scores, -jnp.inf)
-            return blocked_topk(scores, k)
+            with jax.named_scope("score"):
+                scores = mips_scores(l2_normalize(q), corpus)  # (bq, n_padded)
+                if corpus.shape[0] != n_valid:  # pad rows are never candidates
+                    col = jnp.arange(corpus.shape[0])[None, :]
+                    scores = jnp.where(col < n_valid, scores, -jnp.inf)
+            with jax.named_scope("select"):
+                return blocked_topk(scores, k)
 
     elif scorer == "pallas":
         from repro.kernels.mips_topk.kernel import mips_topk_pallas
@@ -237,17 +240,20 @@ class DenseIndex:
             return vals, ids
         # concrete inputs: pad/chunk/reassemble on host so the only XLA work
         # is the fixed-shape closure — batch sizes never trigger op compiles
-        q = np.asarray(query_vecs, np.float32)
-        if pad:
-            q = np.concatenate([q, np.zeros((pad, q.shape[1]), np.float32)], axis=0)
-        vals_np, ids_np = [], []
-        for s in range(0, q.shape[0], Q_BLOCK):
-            v, i = fn(corpus, jnp.asarray(q[s : s + Q_BLOCK]))
-            vals_np.append(np.asarray(v, np.float32))
-            ids_np.append(np.asarray(i, np.int32))
-        vals = np.concatenate(vals_np, axis=0)[:nq] if len(vals_np) > 1 else vals_np[0][:nq]
-        ids = np.concatenate(ids_np, axis=0)[:nq] if len(ids_np) > 1 else ids_np[0][:nq]
-        return jnp.asarray(vals), jnp.asarray(ids)
+        with TraceAnnotation("repro.search", k=k, nq=nq):
+            q = np.asarray(query_vecs, np.float32)
+            if pad:
+                q = np.concatenate([q, np.zeros((pad, q.shape[1]), np.float32)], axis=0)
+            vals_np, ids_np = [], []
+            for chunk, s in enumerate(range(0, q.shape[0], Q_BLOCK)):
+                with TraceAnnotation("repro.search.dispatch", chunk=chunk):
+                    v, i = fn(corpus, jnp.asarray(q[s : s + Q_BLOCK]))
+                with TraceAnnotation("repro.search.fetch", chunk=chunk):
+                    vals_np.append(np.asarray(v, np.float32))
+                    ids_np.append(np.asarray(i, np.int32))
+            vals = np.concatenate(vals_np, axis=0)[:nq] if len(vals_np) > 1 else vals_np[0][:nq]
+            ids = np.concatenate(ids_np, axis=0)[:nq] if len(ids_np) > 1 else ids_np[0][:nq]
+            return jnp.asarray(vals), jnp.asarray(ids)
 
     def search(
         self,
@@ -316,7 +322,8 @@ class DenseIndex:
             idx = jax.lax.axis_index(shard_axes)
             rows = corpus_shard.shape[0]
             start = idx * rows
-            queries = l2_normalize(queries)  # cosine, matching search_batch
+            with jax.named_scope("score"):
+                queries = l2_normalize(queries)  # cosine, matching search_batch
             kk = min(k, rows)
             if scorer == "pallas":
                 bn = _block_width(kk)
@@ -330,14 +337,17 @@ class DenseIndex:
                     valid_mask=mask, interpret=interpret,
                 )
             else:
-                scores = mips_scores(queries, corpus_shard)  # (nq, rows_local)
-                if n_valid is not None:
-                    col = start + jnp.arange(rows)[None, :]
-                    scores = jnp.where(col < n_valid, scores, -jnp.inf)
-                v, i = blocked_topk(scores, kk)
-            i = i + start  # globalize
-            for ax in shard_axes:
-                v, i = distributed_topk(v, i, k, ax)
+                with jax.named_scope("score"):
+                    scores = mips_scores(queries, corpus_shard)  # (nq, rows_local)
+                    if n_valid is not None:
+                        col = start + jnp.arange(rows)[None, :]
+                        scores = jnp.where(col < n_valid, scores, -jnp.inf)
+                with jax.named_scope("select"):
+                    v, i = blocked_topk(scores, kk)
+            with jax.named_scope("merge"):
+                i = i + start  # globalize
+                for ax in shard_axes:
+                    v, i = distributed_topk(v, i, k, ax)
             return v, i
 
         return jax.jit(

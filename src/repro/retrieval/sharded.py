@@ -76,6 +76,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.retrieval.backend import (
     BackendCost,
@@ -648,15 +649,16 @@ class DeviceShardedBackend(ShardedBackend):
         fn, corpus = self._search_fn(k)
         qb = self.q_block
         pad = (-nq) % qb
-        if pad:
-            q = np.concatenate([q, np.zeros((pad, q.shape[1]), np.float32)], axis=0)
-        outs = [
-            fn(corpus, jnp.asarray(q[s : s + qb]))
-            for s in range(0, q.shape[0], qb)
-        ]
-        n_chunks = len(outs)
-        vals = np.concatenate([np.asarray(v, np.float32) for v, _ in outs])[:nq]
-        ids = np.concatenate([np.asarray(i, np.int32) for _, i in outs])[:nq]
+        with TraceAnnotation("repro.search", k=k, nq=nq):
+            if pad:
+                q = np.concatenate([q, np.zeros((pad, q.shape[1]), np.float32)], axis=0)
+            outs = [
+                fn(corpus, jnp.asarray(q[s : s + qb]))
+                for s in range(0, q.shape[0], qb)
+            ]
+            n_chunks = len(outs)
+            vals = np.concatenate([np.asarray(v, np.float32) for v, _ in outs])[:nq]
+            ids = np.concatenate([np.asarray(i, np.int32) for _, i in outs])[:nq]
         self.counters.searches += 1
         self.counters.shard_searches += self._n_shards * n_chunks
         self.counters.merges += n_chunks * len(self.shard_axes)

@@ -80,7 +80,6 @@ class EngineConfig:
     warm_start_telemetry: bool = False
     guardrails: GuardrailConfig = GuardrailConfig()
     realized_norm: RealizedNormalization = RealizedNormalization()
-    measure_wallclock: bool = False  # also record real pipeline wall time
 
 
 @dataclasses.dataclass
@@ -88,7 +87,6 @@ class EngineResponse:
     answer: str
     record: QueryRecord
     passages: list[str]
-    wallclock_ms: float | None = None
 
 
 class RAGEngine:
@@ -142,6 +140,8 @@ class RAGEngine:
         self.guardrails = Guardrails(catalog, config.guardrails)
         self.ledger = BillingLedger(index_embedding_tokens)
         self._query_counter = 0
+        # what answer_batch served: queries routed and replayed, searches
+        self.counts = stages.StageCounts()
 
     # ------------------------------------------------------------------ #
     def _structural_predictions(self) -> tuple[np.ndarray, np.ndarray]:
@@ -329,7 +329,7 @@ class RAGEngine:
             retrieved = stages.retrieve(self, routed)
             admitted = stages.assemble(self, retrieved)
             decoded = stages.decode(self, admitted)
-            return stages.finalize(self, decoded)
+            responses = stages.finalize(self, decoded)
         except BaseException:
             # route() allocated the batch's query ids up front (so pipelined
             # callers can keep routing while earlier batches finalize). In
@@ -343,6 +343,8 @@ class RAGEngine:
             ):
                 self._query_counter = routed.qid0
             raise
+        self.counts.add(decoded)
+        return responses
 
     # ------------------------------------------------------------------ #
     # Batch entry points                                                   #

@@ -36,6 +36,7 @@ sequential loop at every (pipeline_depth, retrieval_workers) setting.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from collections import deque
@@ -45,6 +46,7 @@ from typing import TYPE_CHECKING, Sequence
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.telemetry import QueryRecord
 from repro.core.utility import realized_utility
@@ -103,7 +105,6 @@ class RoutedBatch:
     retrieval_plan: dict[tuple[str, int], list[int]]  # (backend, top_k) → positions
     query_vecs: dict[int, np.ndarray]  # position → (d,) embedded query (vec backends only)
     refinement_on: bool
-    t0: float  # perf_counter at route start (wallclock accounting)
 
     @property
     def n(self) -> int:
@@ -163,6 +164,7 @@ class DecodedBatch:
     search_calls_by_backend: dict[str, int] = dataclasses.field(default_factory=dict)
     cache_events: dict[str, dict[str, int]] = dataclasses.field(default_factory=dict)
     resilience: ResilienceEvents = dataclasses.field(default_factory=ResilienceEvents)
+    replayed: int = 0  # queries finalize re-executed under their true priors
 
     @property
     def routed(self) -> RoutedBatch:
@@ -184,6 +186,53 @@ def merge_cache_events(
         tot = total.setdefault(bname, {})
         for key, v in ev.items():
             tot[key] = tot.get(key, 0) + v
+
+
+def _fold_searches(total, part) -> None:
+    """Add ``part``'s search, cache and resilience counters into ``total``
+    (a :class:`DecodedBatch` or :class:`StageCounts`) in place."""
+    total.search_calls += part.search_calls
+    by = total.search_calls_by_backend
+    for bname, cnt in part.search_calls_by_backend.items():
+        by[bname] = by.get(bname, 0) + cnt
+    merge_cache_events(total.cache_events, part.cache_events)
+    total.resilience.add(part.resilience)
+
+
+@dataclasses.dataclass
+class StageCounts:
+    """Counters summed over finalized batches: what the engine's
+    ``answer_batch`` and a :class:`StagePipeline` each served."""
+
+    routed: int = 0  # queries routed
+    replayed: int = 0  # of those, re-executed by finalize's replay pass
+    search_calls: int = 0  # search_batch calls, replays included
+    search_calls_by_backend: dict[str, int] = dataclasses.field(default_factory=dict)
+    # per-backend cache hit/miss/eviction totals (CachedBackend only)
+    cache_events: dict[str, dict[str, int]] = dataclasses.field(default_factory=dict)
+    # typed resilience totals (retries/timeouts/breaker/ladder outcomes)
+    resilience: ResilienceEvents = dataclasses.field(default_factory=ResilienceEvents)
+
+    def add(self, decoded: DecodedBatch) -> None:
+        """Fold one finalized batch's counters in."""
+        self.routed += decoded.routed.n
+        self.replayed += decoded.replayed
+        _fold_searches(self, decoded)
+
+
+def _batch_span(stage):
+    """Run a stage inside the profiler span ``repro.<stage>``, whose ids
+    (the batch's first query id and size) its other stages' spans share.
+    With the profiler off a span costs about a microsecond."""
+    name = "repro." + stage.__name__
+
+    @functools.wraps(stage)
+    def traced(engine: "RAGEngine", artifact):
+        routed = artifact if isinstance(artifact, RoutedBatch) else artifact.routed
+        with TraceAnnotation(name, qid0=routed.qid0, n=routed.n):
+            return stage(engine, artifact)
+
+    return traced
 
 
 # --------------------------------------------------------------------------- #
@@ -214,7 +263,8 @@ def execute_one(
     qvecs: dict[int, np.ndarray] = {}
     if not bundle.skip_retrieval:
         if engine.backends[bundle.backend].requires_query_vecs:
-            qvecs[0] = np.asarray(engine.embedder.embed([query]), np.float32)[0]
+            with TraceAnnotation("repro.embed", n=1):
+                qvecs[0] = np.asarray(engine.embedder.embed([query]), np.float32)[0]
         plan[(bundle.backend, bundle.top_k)] = [0]
     routed = RoutedBatch(
         qid0=qid,
@@ -227,7 +277,6 @@ def execute_one(
         retrieval_plan=plan,
         query_vecs=qvecs,
         refinement_on=False,
-        t0=0.0,
     )
     return decode(engine, assemble(engine, retrieve(engine, routed)))
 
@@ -279,52 +328,52 @@ def route(
     group, through the engine's query-vector cache). Must be called serially
     in arrival order.
     """
-    t0 = time.perf_counter()
     queries = list(queries)
-    refs = list(references)
-    n = len(queries)
-    qid0 = engine._query_counter
+    with TraceAnnotation("repro.route", qid0=engine._query_counter, n=len(queries)):
+        refs = list(references)
+        n = len(queries)
+        qid0 = engine._query_counter
 
-    cplx_np = np.asarray(engine.router.complexity_batch(queries))
-    lat0, cost0, rec0 = engine._priors()
-    choices, util_np = engine.router.route_batch_np(
-        cplx_np, latency_override=lat0, cost_override=cost0, recall_override=rec0
-    )
+        cplx_np = np.asarray(engine.router.complexity_batch(queries))
+        lat0, cost0, rec0 = engine._priors()
+        choices, util_np = engine.router.route_batch_np(
+            cplx_np, latency_override=lat0, cost_override=cost0, recall_override=rec0
+        )
 
-    guarded = [engine.guardrails.pre_execution(int(c)).bundle_index for c in choices]
-    plan: dict[tuple[str, int], list[int]] = {}
-    for i in range(n):
-        bundle = engine.catalog[guarded[i]]
-        if not bundle.skip_retrieval:
-            plan.setdefault((bundle.backend, bundle.top_k), []).append(i)
-    query_vecs: dict[int, np.ndarray] = {}
-    for (bname, _k), idxs in plan.items():
-        if not engine.backends[bname].requires_query_vecs:
-            continue  # lexical backends never spend the embed call
-        vecs = np.asarray(engine.embedder.embed([queries[i] for i in idxs]), np.float32)
-        for r, i in enumerate(idxs):
-            query_vecs[i] = vecs[r]
+        guarded = [engine.guardrails.pre_execution(int(c)).bundle_index for c in choices]
+        plan: dict[tuple[str, int], list[int]] = {}
+        for i in range(n):
+            bundle = engine.catalog[guarded[i]]
+            if not bundle.skip_retrieval:
+                plan.setdefault((bundle.backend, bundle.top_k), []).append(i)
+        query_vecs: dict[int, np.ndarray] = {}
+        for (bname, _k), idxs in plan.items():
+            if not engine.backends[bname].requires_query_vecs:
+                continue  # lexical backends never spend the embed call
+            with TraceAnnotation("repro.embed", n=len(idxs)):
+                vecs = np.asarray(engine.embedder.embed([queries[i] for i in idxs]), np.float32)
+            for r, i in enumerate(idxs):
+                query_vecs[i] = vecs[r]
 
-    # Allocate the ids only once nothing in this stage can fail: a routing
-    # or embedding error must not leak qids (latency noise and generator
-    # verbosity are seeded per query_id, so a leak would shift every later
-    # record off the reference stream). route is contractually serial, so
-    # deferring the increment cannot race a concurrent allocation.
-    engine._query_counter += n
+        # Allocate the ids only once nothing in this stage can fail: a routing
+        # or embedding error must not leak qids (latency noise and generator
+        # verbosity are seeded per query_id, so a leak would shift every later
+        # record off the reference stream). route is contractually serial, so
+        # deferring the increment cannot race a concurrent allocation.
+        engine._query_counter += n
 
-    return RoutedBatch(
-        qid0=qid0,
-        queries=queries,
-        references=refs,
-        complexity=cplx_np,
-        choices=choices,
-        utilities=util_np,
-        guarded=guarded,
-        retrieval_plan=plan,
-        query_vecs=query_vecs,
-        refinement_on=lat0 is not None,
-        t0=t0,
-    )
+        return RoutedBatch(
+            qid0=qid0,
+            queries=queries,
+            references=refs,
+            complexity=cplx_np,
+            choices=choices,
+            utilities=util_np,
+            guarded=guarded,
+            retrieval_plan=plan,
+            query_vecs=query_vecs,
+            refinement_on=lat0 is not None,
+        )
 
 
 # --------------------------------------------------------------------------- #
@@ -456,6 +505,7 @@ def _degrade_group(
     return calls
 
 
+@_batch_span
 def retrieve(engine: "RAGEngine", routed: RoutedBatch) -> RetrievedBatch:
     """Backend-grouped search: one batched ``search_batch`` call per
     (backend, k) group — the dense groups hit the compiled MIPS closures,
@@ -524,6 +574,7 @@ def retrieve(engine: "RAGEngine", routed: RoutedBatch) -> RetrievedBatch:
 # --------------------------------------------------------------------------- #
 # Stage 3: assemble (pure) — guardrails + passage fetch + prompt build         #
 # --------------------------------------------------------------------------- #
+@_batch_span
 def assemble(engine: "RAGEngine", retrieved: RetrievedBatch) -> AdmittedBatch:
     """Post-retrieval guardrails (low-confidence demotion), passage payload
     fetch, and prompt construction. Pure given the artifact.
@@ -580,6 +631,7 @@ def assemble(engine: "RAGEngine", retrieved: RetrievedBatch) -> AdmittedBatch:
 # --------------------------------------------------------------------------- #
 # Stage 4: decode (pure) — generation, billing, latency, quality               #
 # --------------------------------------------------------------------------- #
+@_batch_span
 def decode(engine: "RAGEngine", admitted: AdmittedBatch) -> DecodedBatch:
     """Generate per query under its final bundle; bill tokens and sample the
     latency model. Pure given the artifact (generator/latency memo caches
@@ -641,6 +693,7 @@ def decode(engine: "RAGEngine", admitted: AdmittedBatch) -> DecodedBatch:
 # --------------------------------------------------------------------------- #
 # Stage 5: finalize (mutates: telemetry, billing ledger; replay fix-up)        #
 # --------------------------------------------------------------------------- #
+@_batch_span
 def finalize(engine: "RAGEngine", decoded: DecodedBatch) -> "list[EngineResponse]":
     """Exact replay + commit. Must be called serially, in arrival order.
 
@@ -681,17 +734,14 @@ def finalize(engine: "RAGEngine", decoded: DecodedBatch) -> "list[EngineResponse
                 guarded = engine.guardrails.pre_execution(choice).bundle_index
                 ex = decoded.exec_cache.get((i, guarded))
                 if ex is None:
-                    sub = execute_one(engine, qid0 + i, queries[i], choice, refs[i])
+                    with TraceAnnotation("repro.replay", qid=qid0 + i):
+                        sub = execute_one(engine, qid0 + i, queries[i], choice, refs[i])
                     ex = sub.executions[0]
                     # fold the one-element replay execution's search/cache
                     # activity into the batch totals (its plan is empty for
                     # skip-retrieval bundles, so the merge is a no-op there)
-                    decoded.search_calls += sub.search_calls
-                    by = decoded.search_calls_by_backend
-                    for bname, cnt in sub.search_calls_by_backend.items():
-                        by[bname] = by.get(bname, 0) + cnt
-                    merge_cache_events(decoded.cache_events, sub.cache_events)
-                    decoded.resilience.add(sub.resilience)
+                    _fold_searches(decoded, sub)
+                    decoded.replayed += 1
                     decoded.exec_cache[(i, guarded)] = ex
                 executions[i] = ex
             sim.log(make_record(engine, qid0 + i, queries[i], executions[i], 0.0, 0.0))
@@ -712,11 +762,6 @@ def finalize(engine: "RAGEngine", decoded: DecodedBatch) -> "list[EngineResponse
         )
     )
 
-    wall = (
-        (time.perf_counter() - routed.t0) * 1000 / n
-        if engine.config.measure_wallclock
-        else None
-    )
     responses = []
     for i, ex in enumerate(executions):
         qid = qid0 + i
@@ -731,11 +776,7 @@ def finalize(engine: "RAGEngine", decoded: DecodedBatch) -> "list[EngineResponse
             complexity=float(routed.complexity[i]),
         )
         engine.telemetry.log(record)
-        responses.append(
-            EngineResponse(
-                answer=ex.answer, record=record, passages=ex.passages, wallclock_ms=wall
-            )
-        )
+        responses.append(EngineResponse(answer=ex.answer, record=record, passages=ex.passages))
     return responses
 
 
@@ -844,12 +885,7 @@ class StagePipeline:
         self._inflight: deque[tuple[object, Future | DecodedBatch, tuple[int, int, int]]] = deque()
         # deterministic per-stage counters (the CI gate's burst-serial cell)
         self.stage_batches = 0
-        self.retrieve_calls = 0
-        self.retrieve_calls_by_backend: dict[str, int] = {}
-        # per-backend cache hit/miss/eviction totals (CachedBackend only)
-        self.cache_events: dict[str, dict[str, int]] = {}
-        # typed resilience totals (retries/timeouts/breaker/ladder outcomes)
-        self.resilience = ResilienceEvents()
+        self.counts = StageCounts()
         # per-micro-batch worker liveness: each worker beats at batch start
         # and end, so a worker stuck *inside* a batch for > worker_timeout_s
         # shows up in stalled_workers() (training/fault_tolerance reuse)
@@ -874,6 +910,23 @@ class StagePipeline:
         surfaces. Idle workers never report (no batch in hand, no deadline)."""
         dead = set(self.heartbeats.dead_workers())
         return sorted(w for w in list(self._busy) if w in dead)
+
+    @property
+    def retrieve_calls(self) -> int:
+        """search_batch calls of the finalized batches, replays included."""
+        return self.counts.search_calls
+
+    @property
+    def retrieve_calls_by_backend(self) -> dict[str, int]:
+        return self.counts.search_calls_by_backend
+
+    @property
+    def cache_events(self) -> dict[str, dict[str, int]]:
+        return self.counts.cache_events
+
+    @property
+    def resilience(self) -> ResilienceEvents:
+        return self.counts.resilience
 
     @property
     def in_flight(self) -> int:
@@ -957,13 +1010,7 @@ class StagePipeline:
             decoded = work
         self._inflight.popleft()
         responses = finalize(self.engine, decoded)
-        self.retrieve_calls += decoded.search_calls
-        for bname, n in decoded.search_calls_by_backend.items():
-            self.retrieve_calls_by_backend[bname] = (
-                self.retrieve_calls_by_backend.get(bname, 0) + n
-            )
-        merge_cache_events(self.cache_events, decoded.cache_events)
-        self.resilience.add(decoded.resilience)
+        self.counts.add(decoded)
         return tag, responses
 
     def wait_head(self, timeout: float) -> None:
