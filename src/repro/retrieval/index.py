@@ -9,8 +9,9 @@ cosine similarity ("FAISS inner-product index", §V.E). Three search paths:
   a thousand — runs the same compiled program and nothing retraces per query.
   ``scorer`` selects the implementation:
 
-  - ``"blocked"`` (default): block matmul (``topk.mips_scores``) +
-    running top-k (``topk.blocked_topk``) — the served scorer.
+  - ``"blocked"`` (default): block matmul (``topk.mips_scores``) + exact
+    two-stage top-k by 128-column group maxima (``topk.blocked_topk``),
+    which never sorts the score row — the served scorer.
   - ``"pallas"``: the fused Pallas ``mips_topk`` TPU kernel
     (``kernels.mips_topk``). Pass ``interpret=True`` to run it off-TPU.
 
@@ -89,7 +90,7 @@ def search_program(
     """The jit-compiled single-device search: ``(corpus, (Q_BLOCK, d)) →
     (scores (Q_BLOCK, k), ids (Q_BLOCK, k))`` over a corpus zero-padded to
     whole score blocks, whose rows past ``n_valid`` are never candidates.
-    ``scorer`` is ``"blocked"`` (block matmul + running top-k) or
+    ``scorer`` is ``"blocked"`` (block matmul + two-stage top-k) or
     ``"pallas"`` (the fused ``mips_topk`` kernel)."""
     if scorer == "blocked":
 
@@ -290,7 +291,7 @@ class DenseIndex:
 
         Corpus rows are sharded over ``shard_axes`` (e.g. ``("data","model")``
         → 256-way row sharding); queries are replicated; each shard scores
-        its rows (``scorer="blocked"`` matmul + running top-k, or
+        its rows (``scorer="blocked"`` matmul + two-stage top-k, or
         ``"pallas"`` for the fused ``mips_topk`` kernel per shard), computes
         a local top-k, and the k-candidate lists merge with one all-gather
         per axis — the whole search is a single device program with no host

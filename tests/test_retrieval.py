@@ -20,6 +20,7 @@ from repro.retrieval import (
     count_tokens,
     kmeans,
     lexical_overlap,
+    l2_normalize,
     line_passages,
     merge_topk,
     rrf_fuse,
@@ -27,6 +28,8 @@ from repro.retrieval import (
     terms,
     weighted_fuse,
 )
+from repro.retrieval.index import _block_width, search_program
+from repro.retrieval.topk import mips_scores
 
 EMB = HashedNGramEmbedder(dim=128)
 
@@ -172,6 +175,87 @@ def test_blocked_topk_property(k, n):
     x = jnp.asarray(rng.normal(size=(n,)).astype(np.float32))
     bv, _ = blocked_topk(x, k, block=32)
     np.testing.assert_allclose(np.asarray(bv), np.sort(np.asarray(x))[::-1][:k], rtol=1e-6)
+
+
+def _tied_rows(shape, seed, n_inf=40, offset=0.0):
+    """Integer-valued scores, most of them tied, fewer the higher they lie
+    (so a top-k runs through ties across groups whose maxima differ), whose
+    last ``n_inf`` columns are ``-inf``, as the masked pad rows of a search
+    are."""
+    x = np.floor(np.random.default_rng(seed).exponential(2.0, size=shape)) + offset
+    x = x.astype(np.float32)
+    x[..., x.shape[-1] - n_inf :] = -np.inf
+    return x
+
+
+def _row_case(make, k, **kw):
+    def run():
+        x = jnp.asarray(make())
+        return (*blocked_topk(x, k, **kw), x, k)
+
+    return run
+
+
+def _search_case(k):
+    """``search_program`` over a corpus of repeated rows (tied scores), its
+    last block masked past ``n_valid``, against ``lax.top_k`` of the same
+    scores."""
+
+    def run():
+        n_valid, dim = 6_000, 16
+        rng = np.random.default_rng(k)
+        rows = np.asarray(l2_normalize(jnp.asarray(rng.normal(size=(50, dim)), jnp.float32)))
+        corpus = np.zeros((n_valid + (-n_valid) % _block_width(k), dim), np.float32)
+        corpus[:n_valid] = rows[rng.integers(0, 50, size=n_valid)]
+        q = jnp.asarray(np.concatenate([rows[:4], rng.normal(size=(4, dim))]), jnp.float32)
+        corpus = jnp.asarray(corpus)
+        vals, ids = search_program(k, n_valid)(corpus, q)
+        scores = jax.jit(lambda c, q: mips_scores(l2_normalize(q), c))(corpus, q)
+        scores = jnp.where(jnp.arange(corpus.shape[0]) < n_valid, scores, -jnp.inf)
+        return vals, ids, scores, k
+
+    return run
+
+
+def _bit_pattern_rows():
+    """Random f32 bit patterns salted with ±0, ±inf and NaNs of both signs."""
+    rng = np.random.default_rng(7)
+    bits = rng.integers(-(2**31), 2**31, size=(3, 6_000), dtype=np.int64).astype(np.int32)
+    salt = np.array([0, -(2**31), 0x7FC00000, -0x400000, 0x7F800000, -0x800000], np.int32)
+    mask = rng.random(bits.shape) < 0.5
+    bits[mask] = rng.choice(salt, size=int(mask.sum()))
+    return bits.view(np.float32)
+
+
+# (k, n): the two-stage path starts at n = 4·k·128 columns
+_TOPK_CASES = {
+    **{
+        f"ties-k{k}-n{n}": _row_case(lambda k=k, n=n: _tied_rows((8, n), seed=k + n), k)
+        for k in (1, 3, 5, 10, 100)
+        for n in (4 * k * 128 - 1, 4 * k * 128 + 77)
+    },
+    "ties-k1-n100000-twice-grouped": _row_case(lambda: _tied_rows((8, 100_000), seed=1), 1),
+    "ties-k10-n300001-twice-grouped": _row_case(lambda: _tied_rows((8, 300_001), seed=2), 10),
+    "ties-batch-axis": _row_case(lambda: _tied_rows((2, 3, 9_000), seed=3), 5),
+    "ties-one-row": _row_case(lambda: _tied_rows((9_000,), seed=4), 3),
+    "ties-narrow-groups": _row_case(lambda: _tied_rows((8, 5_000), seed=5), 3, block=8),
+    "ties-all-negative": _row_case(lambda: _tied_rows((8, 7_000), seed=6, offset=-40.0), 10),
+    "all-equal": _row_case(lambda: np.zeros((8, 7_000), np.float32), 10),
+    "signed-zeros-and-nans": _row_case(_bit_pattern_rows, 5),
+    **{f"search-program-k{k}": _search_case(k) for k in (3, 5, 10)},
+}
+
+
+@pytest.mark.parametrize("case", list(_TOPK_CASES))
+def test_blocked_topk_is_lax_topk_bit_for_bit(case):
+    """The two-stage selection returns ``lax.top_k`` over the whole row:
+    the same value bits and the same ids, lowest id first among ties."""
+    vals, ids, scores, k = _TOPK_CASES[case]()
+    ref_vals, ref_ids = jax.lax.top_k(scores, k)
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(ref_ids))
+    np.testing.assert_array_equal(
+        np.asarray(vals).view(np.int32), np.asarray(ref_vals).view(np.int32)
+    )
 
 
 def test_merge_topk():

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from repro.kernels import SCORE_BLOCK
 from repro.kernels.mips_topk.kernel import mips_topk_pallas
-from repro.retrieval.index import Q_BLOCK, DenseIndex, search_program
+from repro.retrieval.index import Q_BLOCK, DenseIndex, _block_width, search_program
 
 N_DOCS, DIM = 1_000_000, 768  # a million BERT-base-width passages, f32
 N_PADDED = math.ceil(N_DOCS / SCORE_BLOCK) * SCORE_BLOCK
@@ -80,6 +81,30 @@ def test_dense_search_program_takes_the_corpus_as_an_argument(one_chip, scorer):
     assert corpus_bytes <= mem.argument_size_in_bytes < corpus_bytes * 1.01
     # the program holds no second copy of the corpus
     assert mem.temp_size_in_bytes < corpus_bytes / 10
+
+
+# the benchmark's corpora: BEIR NQ at 768-d and BEIR HotpotQA at 384-d
+_CELL_SHAPES = {"nq-768": (2_681_468, 768), "hotpotqa-384": (5_233_329, 384)}
+
+
+@pytest.mark.parametrize("k", [3, 5, 10])
+@pytest.mark.parametrize("shape", list(_CELL_SHAPES))
+def test_served_search_sorts_no_score_block(one_chip, shape, k):
+    """The served program selects its top-k by group maxima at the
+    benchmark's shapes: no sort in it spans 4096 or more score columns, and
+    it holds no copy of the corpus."""
+    rows, dim = _CELL_SHAPES[shape]
+    padded = rows + (-rows) % _block_width(k)
+    q = jax.ShapeDtypeStruct((Q_BLOCK, dim), jnp.float32, sharding=one_chip)
+    c = jax.ShapeDtypeStruct((padded, dim), jnp.float32, sharding=one_chip)
+    compiled = search_program(k, rows).lower(c, q).compile()
+    sorts = re.findall(r"= (.*?) sort\(", compiled.as_text())
+    assert sorts  # lax.top_k lowers to sorts on the TPU: the check reads them
+    widths = [int(w) for s in sorts for dims in re.findall(r"\[([\d,]+)\]", s)
+              for w in dims.split(",")]
+    assert max(widths) < 4096, sorts
+    corpus_bytes = padded * dim * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < corpus_bytes / 10
 
 
 def test_four_chip_sharded_search_puts_a_quarter_on_each_device(topo):
