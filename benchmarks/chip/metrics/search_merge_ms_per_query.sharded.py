@@ -1,0 +1,9 @@
+"""Device time of the sharded search program's ``merge`` scope (id
+globalisation, the all-gather of the chips' candidates, the last top-k), the
+mean over the chips, ms per query searched (row-sharded cells)."""
+
+from chipbench.sharded_trace import scope_ms_per_query
+
+
+def read(run):
+    return scope_ms_per_query(run, "merge")

@@ -314,6 +314,18 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
+def _shard_counts(backends) -> str:
+    """The sharded dense backend's work counters, found under any wrappers
+    (cache, faults, resilience), for the ``served:`` line; empty where the
+    dense backend is not sharded."""
+    from repro.retrieval import ShardedBackend
+
+    dense = backends.get("dense")
+    while dense is not None and not isinstance(dense, ShardedBackend):
+        dense = getattr(dense, "inner", None)
+    return "" if dense is None else f", dense shards {dense.counters.as_dict()}"
+
+
 def main() -> None:
     ap = build_parser()
     args = ap.parse_args()
@@ -398,7 +410,8 @@ def main() -> None:
         counts = engine.counts
         print(f"served: {counts.routed} routed, {counts.replayed} replayed "
               f"({100 * counts.replayed / max(1, counts.routed):.2f}%), "
-              f"searches by backend {counts.search_calls_by_backend}")
+              f"searches by backend {counts.search_calls_by_backend}"
+              f"{_shard_counts(engine.backends)}")
     if args.catalog != "paper":
         # (backend × depth) routing view: which retrieval method served what
         print(f"routed by backend: {catalog.routed_by_backend(telemetry.strategy_counts())}")

@@ -34,6 +34,7 @@ logged per query and consumed by the low-confidence guardrail.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable, Sequence
 
 import jax
@@ -154,6 +155,13 @@ class DenseIndex:
         self._fn_cache: dict[tuple, tuple[Callable, jax.Array]] = {}
         # block width → device corpus zero-padded to a multiple of it
         self._padded_corpus: dict[int, jax.Array] = {}
+        # The device-sharded search's state (device_sharded_search) lives
+        # here too, so every backend and engine over this index shares it:
+        # (mesh, shard axes, rows per shard) → the corpus placed across the
+        # mesh, and that key plus (k, scorer, interpret, n_valid) → the
+        # compiled shard_map program.
+        self._sharded_corpus: dict[tuple, jax.Array] = {}
+        self._sharded_fn_cache: dict[tuple, Callable] = {}
 
     # -- construction ---------------------------------------------------------
     @classmethod
@@ -277,6 +285,60 @@ class DenseIndex:
         return [self.passages[int(i)] for i in ids]
 
     # -- distributed search -------------------------------------------------------
+    def device_sharded_search(
+        self,
+        mesh: jax.sharding.Mesh,
+        k: int,
+        shard_axes: tuple[str, ...],
+        *,
+        scorer: str = "blocked",
+        interpret: bool = False,
+    ) -> tuple[Callable, jax.Array]:
+        """The :meth:`sharded_search_fn` program for ``k`` and the corpus it
+        takes, placed across ``mesh``: built on first use and kept on this
+        index, so every backend over it (one per engine) shares one placed
+        copy per device and one compiled program per ``k``.
+
+        Each shard holds its share of the rows, padded to whole score blocks
+        (``_block_width(k)``) so it scores exactly as the unsharded index;
+        rows past the corpus are zeros that the program masks
+        (``n_valid``). Each shard is placed straight from the host onto its
+        own device, so no device ever holds more than its shard.
+        """
+        n_shards = int(np.prod([mesh.shape[a] for a in shard_axes]))
+        bn = _block_width(k)
+        rows_per = math.ceil(math.ceil(self.size / n_shards) / bn) * bn
+        n_valid = self.size if rows_per * n_shards != self.size else None
+        placement = (mesh, tuple(shard_axes), rows_per)
+        key = placement + (k, scorer, interpret, n_valid)
+        fn = self._sharded_fn_cache.get(key)
+        if fn is None:
+            fn, _ = self.sharded_search_fn(
+                mesh, k, shard_axes, scorer=scorer, interpret=interpret, n_valid=n_valid
+            )
+            self._sharded_fn_cache[key] = fn
+        corpus = self._sharded_corpus.get(placement)
+        if corpus is None:
+            from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as P
+
+            host = np.asarray(self.embeddings, np.float32)
+            n, d = host.shape
+            total = rows_per * n_shards
+
+            def shard_rows(index: tuple[slice, ...]) -> np.ndarray:
+                start, stop, _ = index[0].indices(total)
+                rows = host[min(start, n) : min(stop, n)]
+                if rows.shape[0] < stop - start:  # the padded tail shard(s)
+                    fill = np.zeros((stop - start - rows.shape[0], d), np.float32)
+                    rows = np.concatenate([rows, fill])
+                return rows
+
+            corpus = self._sharded_corpus[placement] = jax.make_array_from_callback(
+                (total, d), NamedSharding(mesh, P(tuple(shard_axes), None)), shard_rows
+            )
+        return fn, corpus
+
     def sharded_search_fn(
         self,
         mesh: jax.sharding.Mesh,

@@ -67,7 +67,6 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
-import math
 import multiprocessing
 import os
 from concurrent.futures import ThreadPoolExecutor
@@ -86,7 +85,7 @@ from repro.retrieval.backend import (
     RetrievalBackend,
 )
 from repro.retrieval.chunking import Passage
-from repro.retrieval.index import Q_BLOCK, DenseIndex, _block_width
+from repro.retrieval.index import Q_BLOCK, DenseIndex
 from repro.retrieval.topk import merge_topk
 from repro.runtime import accelerator_attached, refuse_children_on_accelerator
 
@@ -478,8 +477,11 @@ class DeviceShardedBackend(ShardedBackend):
 
     The corpus (zero-padded to an S-divisible row count) is placed **once**
     across the mesh with the :func:`mesh_layout` corpus spec and stays
-    device-resident; every search dispatches the cached jit'd
-    ``shard_map`` closure built by ``DenseIndex.sharded_search_fn`` —
+    device-resident; every search dispatches the jit'd ``shard_map``
+    closure built by ``DenseIndex.sharded_search_fn``. Both are the
+    index's (:meth:`DenseIndex.device_sharded_search`), so every backend
+    over one index and one mesh — one per engine — shares them: a second
+    engine's first search places and compiles nothing. In that program
     per-shard scoring (blocked matmul or the pallas ``mips_topk`` kernel
     with a traced residue mask), local top-k, id globalization by
     ``axis_index * rows_per_shard``, and the cross-shard
@@ -540,9 +542,6 @@ class DeviceShardedBackend(ShardedBackend):
         # bit-identical either way — chunking only tiles the query axis).
         self.q_block = int(q_block) if q_block is not None else Q_BLOCK
         self.counters = ShardCounters()
-        # k → compiled shard_map closure; rows_per → placed padded corpus
-        self._fn_cache: dict[int, object] = {}
-        self._corpus_cache: dict[int, jnp.ndarray] = {}
 
     @property
     def n_shards(self) -> int:
@@ -562,66 +561,6 @@ class DeviceShardedBackend(ShardedBackend):
     @shards.setter
     def shards(self, _value):  # dataclass-free __init__ never sets this
         raise AttributeError("device shards are mesh-resident")
-
-    # -- device program construction ------------------------------------------
-    def _rows_per_shard(self, k: int) -> int:
-        """Rows each shard holds: its share of the corpus, padded to whole
-        score blocks so every shard scores exactly as the unsharded index."""
-        bn = _block_width(k)
-        return math.ceil(math.ceil(self.size / self._n_shards) / bn) * bn
-
-    def _placed_corpus(self, rows_per: int) -> jax.Array:
-        """The corpus zero-padded to ``rows_per`` rows per shard, each shard
-        placed straight from the host onto its own device: no device ever
-        holds more than its shard, so a corpus larger than one chip never
-        stages whole on the first."""
-        corpus = self._corpus_cache.get(rows_per)
-        if corpus is None:
-            from jax.sharding import NamedSharding
-
-            from repro.distributed.partition import ShardingPolicy
-
-            # the mesh_layout() corpus spec, parameterized by this mesh's
-            # actual axis names (a custom mesh may not call its axis "data")
-            corpus_spec, _, _ = mesh_layout(ShardingPolicy(data_axes=self.shard_axes))
-            host = np.asarray(self.index.embeddings, np.float32)
-            n, d = host.shape
-
-            def shard_rows(index: tuple[slice, ...]) -> np.ndarray:
-                start, stop, _ = index[0].indices(rows_per * self._n_shards)
-                rows = host[min(start, n) : min(stop, n)]
-                if rows.shape[0] < stop - start:  # the padded tail shard(s)
-                    fill = np.zeros((stop - start - rows.shape[0], d), np.float32)
-                    rows = np.concatenate([rows, fill])
-                return rows
-
-            corpus = jax.make_array_from_callback(
-                (rows_per * self._n_shards, d),
-                NamedSharding(self.mesh, corpus_spec),
-                shard_rows,
-            )
-            self._corpus_cache[rows_per] = corpus
-        return corpus
-
-    def _search_fn(self, k: int):
-        """Cached ``(corpus, (Q_BLOCK, d)) → ((Q_BLOCK, k), (Q_BLOCK, k))``
-        shard_map closure + its placed corpus, compiled once per k."""
-        entry = self._fn_cache.get(k)
-        if entry is not None:
-            return entry
-        rows_per = self._rows_per_shard(k)
-        padded = rows_per * self._n_shards
-        fn, _ = self.index.sharded_search_fn(
-            self.mesh,
-            k,
-            self.shard_axes,
-            scorer=self.scorer,
-            interpret=self.interpret,
-            n_valid=self.size if padded != self.size else None,
-        )
-        entry = (fn, self._placed_corpus(rows_per))
-        self._fn_cache[k] = entry
-        return entry
 
     # -- search ---------------------------------------------------------------
     def search_batch(
@@ -646,19 +585,26 @@ class DeviceShardedBackend(ShardedBackend):
         nq = q.shape[0]
         if nq == 0:
             return np.zeros((0, k), np.float32), np.zeros((0, k), np.int32)
-        fn, corpus = self._search_fn(k)
+        fn, corpus = self.index.device_sharded_search(
+            self.mesh, k, self.shard_axes, scorer=self.scorer, interpret=self.interpret
+        )
         qb = self.q_block
         pad = (-nq) % qb
         with TraceAnnotation("repro.search", k=k, nq=nq):
             if pad:
                 q = np.concatenate([q, np.zeros((pad, q.shape[1]), np.float32)], axis=0)
-            outs = [
-                fn(corpus, jnp.asarray(q[s : s + qb]))
-                for s in range(0, q.shape[0], qb)
-            ]
+            outs = []
+            for chunk, s in enumerate(range(0, q.shape[0], qb)):
+                with TraceAnnotation("repro.search.dispatch", chunk=chunk):
+                    outs.append(fn(corpus, jnp.asarray(q[s : s + qb])))
             n_chunks = len(outs)
-            vals = np.concatenate([np.asarray(v, np.float32) for v, _ in outs])[:nq]
-            ids = np.concatenate([np.asarray(i, np.int32) for _, i in outs])[:nq]
+            vals_np, ids_np = [], []
+            for chunk, (v, i) in enumerate(outs):
+                with TraceAnnotation("repro.search.fetch", chunk=chunk):
+                    vals_np.append(np.asarray(v, np.float32))
+                    ids_np.append(np.asarray(i, np.int32))
+            vals = np.concatenate(vals_np)[:nq]
+            ids = np.concatenate(ids_np)[:nq]
         self.counters.searches += 1
         self.counters.shard_searches += self._n_shards * n_chunks
         self.counters.merges += n_chunks * len(self.shard_axes)

@@ -172,6 +172,50 @@ def test_serve_cli_prints_the_engines_counts(tmp_path):
             f"searches by backend {c.search_calls_by_backend}") in proc.stdout.splitlines()
 
 
+def test_serve_cli_prints_the_dense_shard_counters(tmp_path):
+    from repro.launch.serve import _ENGINE_OPT_KEYS, build_engine_from_opts, build_parser
+
+    args = ["--policy", "router_default", "--shards", "1", "--shard-execution", "device"]
+    proc = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", *args, "--out", str(tmp_path / "s.csv")],
+        capture_output=True, text=True, timeout=600,
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": str(tmp_path),
+             "JAX_PLATFORMS": "cpu"},
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    opts = vars(build_parser().parse_args(args))
+    engine = build_engine_from_opts({key: opts[key] for key in _ENGINE_OPT_KEYS})
+    engine.run(QUERIES, REFS)
+    c, shards = engine.counts, engine.backends["dense"].counters
+    assert shards.searches == c.search_calls_by_backend["dense"] > 0
+    assert (f"served: {c.routed} routed, {c.replayed} replayed "
+            f"({100 * c.replayed / c.routed:.2f}%), "
+            f"searches by backend {c.search_calls_by_backend}, "
+            f"dense shards {shards.as_dict()}") in proc.stdout.splitlines()
+
+
+def test_sharded_search_dispatches_every_chunk_before_reading_any(tmp_path):
+    from repro.retrieval import ShardedBackend
+
+    index = DenseIndex(np.eye(64, dtype=np.float32)[:50], assume_normalized=True)
+    dev = ShardedBackend.from_dense(index, n_shards=1, execution="device")
+    q = np.ones((20, 64), np.float32)  # three 8-query chunks
+    dev.search_batch(None, q, 5)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        dev.search_batch(None, q, 5)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _spans(tmp_path)
+    (search,) = [s for s in spans if s[0] == "repro.search"]
+    assert search[3] == {"k": 5, "nq": 20}
+    parts = [s for s in spans if s[0].startswith("repro.search.")]
+    assert [(s[0], s[3]["chunk"]) for s in parts] == (
+        [("repro.search.dispatch", c) for c in range(3)]
+        + [("repro.search.fetch", c) for c in range(3)])
+    assert all(_within(s, search) for s in parts)
+
+
 def test_search_program_scopes():
     text = search_program(5, 5000).lower(
         jax.ShapeDtypeStruct((5120, 64), jnp.float32), jax.ShapeDtypeStruct((8, 64), jnp.float32)

@@ -105,6 +105,39 @@ def test_device_counters_and_chunking():
     _assert_identical(wide, dev, q, 10)  # chunk width never moves a result
 
 
+def test_device_backends_over_one_index_share_its_placed_corpus_and_programs():
+    """The placed shards and the compiled programs are the index's: a second
+    backend over the same index and mesh (as a second engine builds) places
+    and compiles nothing, and its answers are the first's."""
+    idx = _tie_corpus()
+    first = ShardedBackend.from_dense(idx, n_shards=1, execution="device")
+    q = _queries(nq=10)
+    first.search_batch(None, q, 5)
+    first.search_batch(None, q, 13)
+    assert len(idx._sharded_corpus) == 1  # one block width for both k
+    assert len(idx._sharded_fn_cache) == 2
+    (corpus,) = idx._sharded_corpus.values()
+    programs = dict(idx._sharded_fn_cache)
+    compiles = []
+
+    def on_event(event, duration, **_):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    second = ShardedBackend.from_dense(idx, n_shards=1, execution="device")
+    assert second is not first
+    for k in (5, 13):
+        _assert_identical(second, first, q, k)
+    assert compiles == []
+    assert idx._sharded_fn_cache == programs
+    assert next(iter(idx._sharded_corpus.values())) is corpus
+    # another index places and compiles its own
+    other = _tie_corpus(seed=3)
+    ShardedBackend.from_dense(other, n_shards=1, execution="device").search_batch(None, q, 5)
+    assert len(other._sharded_corpus) == 1 and idx._sharded_fn_cache == programs
+
+
 def test_device_empty_batch_and_payloads():
     idx = _tie_corpus()
     dev = ShardedBackend.from_dense(idx, n_shards=1, execution="device")
