@@ -406,10 +406,12 @@ def main() -> None:
     print(telemetry.summary_json())
     if not args.stream:
         # what answer_batch served: replays are re-executions whose refined
-        # route flipped, each one more full search of the index
+        # route flipped; a flip of depth on a nested backend is served from
+        # the first pass's row, every other one searches the index again
         counts = engine.counts
         print(f"served: {counts.routed} routed, {counts.replayed} replayed "
               f"({100 * counts.replayed / max(1, counts.routed):.2f}%), "
+              f"{counts.replay_searches} replay searches, "
               f"searches by backend {counts.search_calls_by_backend}"
               f"{_shard_counts(engine.backends)}")
     if args.catalog != "paper":

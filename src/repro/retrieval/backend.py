@@ -21,6 +21,11 @@ Contracts every adapter honors:
   ``(n, d)`` matrix; an adapter reads whichever representation it needs
   (``requires_query_vecs`` tells the serving layer whether to spend the
   embed call at all — BM25 never does).
+* ``nested_topk`` (optional, false when absent) declares that a backend's
+  top-k rows nest: for k' < k, the top-k' row is the first k' entries of
+  the top-k row, bit for bit. The serving stages then search such a
+  backend once, at the deepest k the catalog routes to it, and serve every
+  shallower depth from that row's prefix.
 * Rows come back descending by fused/backend score, ids are passage ids
   into the shared corpus, and ``k`` is clamped to the corpus size.
 * Results are deterministic pure functions of (corpus, query, k): the
@@ -164,6 +169,11 @@ class RetrievalBackend(Protocol):
         ...
 
 
+def is_nested(backend: object) -> bool:
+    """Whether ``backend`` declares ``nested_topk`` (false when absent)."""
+    return bool(getattr(backend, "nested_topk", False))
+
+
 class DenseBackend:
     """Exact MIPS through the jit/pallas :class:`DenseIndex` path.
 
@@ -174,6 +184,8 @@ class DenseBackend:
 
     name = "dense"
     requires_query_vecs = True
+    # both scorers order ties by the lowest id, so top-k' is top-k's prefix
+    nested_topk = True
 
     def __init__(self, index: DenseIndex, *, scorer: str = "blocked", interpret: bool = False):
         self.index = index

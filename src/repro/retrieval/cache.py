@@ -42,7 +42,7 @@ from typing import Mapping, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from repro.retrieval.backend import BackendCost, RetrievalBackend
+from repro.retrieval.backend import BackendCost, RetrievalBackend, is_nested
 from repro.retrieval.chunking import Passage
 
 
@@ -108,6 +108,11 @@ class CachedBackend:
     def requires_query_vecs(self) -> bool:
         """Whether the inner backend consumes embedded query vectors."""
         return self.inner.requires_query_vecs
+
+    @property
+    def nested_topk(self) -> bool:
+        """The inner backend's: a cached row is the inner backend's row."""
+        return is_nested(self.inner)
 
     @property
     def size(self) -> int:

@@ -83,6 +83,7 @@ from repro.retrieval.backend import (
     DenseBackend,
     IVFBackend,
     RetrievalBackend,
+    is_nested,
 )
 from repro.retrieval.chunking import Passage
 from repro.retrieval.index import Q_BLOCK, DenseIndex
@@ -252,6 +253,8 @@ class ShardedBackend:
         self.name = name if name is not None else self.shards[0].name
         self.cost = cost if cost is not None else self.shards[0].cost
         self.requires_query_vecs = any(s.requires_query_vecs for s in self.shards)
+        # the lowest-id-first merge keeps nested shards' rows nested
+        self.nested_topk = all(is_nested(s) for s in self.shards)
         self.workers = max(0, int(workers))
         self._pool = ThreadPoolExecutor(max_workers=self.workers) if self.workers > 1 else None
         self.counters = ShardCounters()
@@ -498,6 +501,8 @@ class DeviceShardedBackend(ShardedBackend):
     """
 
     execution = "device"
+    # distributed_topk keeps the lowest global id among ties, as top_k does
+    nested_topk = True
 
     def __init__(
         self,
@@ -689,6 +694,8 @@ class ProcessShardedBackend(ShardedBackend):
     """
 
     execution = "process"
+    # dense shard workers, merged lowest id first as on the threads path
+    nested_topk = True
 
     def __init__(
         self,

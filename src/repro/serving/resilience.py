@@ -47,7 +47,7 @@ from typing import Callable, Mapping, Sequence
 import numpy as np
 
 from repro.core.bundles import BundleCatalog
-from repro.retrieval.backend import BackendCost, RetrievalBackend
+from repro.retrieval.backend import BackendCost, RetrievalBackend, is_nested
 from repro.retrieval.chunking import Passage
 from repro.retrieval.faults import RetrievalFault, TransientBackendError
 
@@ -318,6 +318,11 @@ class ResilientBackend:
     def requires_query_vecs(self) -> bool:
         """Whether the inner backend consumes embedded query vectors."""
         return self.inner.requires_query_vecs
+
+    @property
+    def nested_topk(self) -> bool:
+        """The inner backend's: a row it answers is the inner backend's row."""
+        return is_nested(self.inner)
 
     @property
     def size(self) -> int:

@@ -6,7 +6,7 @@ carries everything the next stage needs, so a stage never reaches back into
 the engine for per-query state:
 
     route(queries)            -> RoutedBatch      (qids, priors, speculation)
-    retrieve(RoutedBatch)     -> RetrievedBatch   (searches grouped by (backend, k))
+    retrieve(RoutedBatch)     -> RetrievedBatch   (searches grouped by (backend, depth))
     assemble(RetrievedBatch)  -> AdmittedBatch    (guardrails + prompt build)
     decode(AdmittedBatch)     -> DecodedBatch     (generation, billing, latency)
     finalize(DecodedBatch)    -> list[EngineResponse]  (replay, ledger, telemetry)
@@ -31,6 +31,12 @@ priors (a deep pipeline routes micro-batch b before b-1 has finalized), but
 (:meth:`TelemetryStore.clone_for_replay`) and re-executes any query whose
 true-prior routing differs, so drained records are bit-identical to the
 sequential loop at every (pipeline_depth, retrieval_workers) setting.
+
+Search depth: a backend that declares ``nested_topk`` (its top-k' row is the
+prefix of its top-k row, bit for bit) is searched once per batch, at the
+deepest k the catalog routes to it (:func:`search_depths`); ``assemble``
+slices each row to its bundle's k, and a replay that only changes the depth
+on that backend is served from the first pass's row instead of searching.
 """
 
 from __future__ import annotations
@@ -50,6 +56,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.core.telemetry import QueryRecord
 from repro.core.utility import realized_utility
+from repro.retrieval.backend import is_nested
 from repro.retrieval.faults import RetrievalFault
 from repro.retrieval.tokenizer import lexical_overlap
 from repro.serving.billing import TokenBill, bill_query
@@ -102,7 +109,9 @@ class RoutedBatch:
     choices: np.ndarray  # (n,) int32 — speculative routed bundle per query
     utilities: np.ndarray  # (n, B) — Eq. 1 utilities under route-time priors
     guarded: list[int]  # pre-execution guardrail outcome per query
-    retrieval_plan: dict[tuple[str, int], list[int]]  # (backend, top_k) → positions
+    # (backend, search depth) → positions: the depth is search_depths' for a
+    # nested backend, else the bundle's top_k
+    retrieval_plan: dict[tuple[str, int], list[int]]
     query_vecs: dict[int, np.ndarray]  # position → (d,) embedded query (vec backends only)
     refinement_on: bool
 
@@ -115,11 +124,11 @@ class RoutedBatch:
 @dataclasses.dataclass
 class RetrievedBatch:
     """Output of :func:`retrieve`: per-position (scores, ids) rows from the
-    backend-grouped batched searches."""
+    backend-grouped batched searches, each as deep as its group searched."""
 
     routed: RoutedBatch
-    retrievals: dict[int, tuple[np.ndarray, np.ndarray]]  # position → (k,) rows
-    search_calls: int  # search_batch invocations (one per (backend, k) group)
+    retrievals: dict[int, tuple[np.ndarray, np.ndarray]]  # position → (depth,) rows
+    search_calls: int  # search_batch invocations (one per plan group)
     search_calls_by_backend: dict[str, int] = dataclasses.field(default_factory=dict)
     # per-backend cache hit/miss/eviction deltas incurred by this batch's
     # searches (CachedBackend-wrapped backends only; empty otherwise)
@@ -165,6 +174,7 @@ class DecodedBatch:
     cache_events: dict[str, dict[str, int]] = dataclasses.field(default_factory=dict)
     resilience: ResilienceEvents = dataclasses.field(default_factory=ResilienceEvents)
     replayed: int = 0  # queries finalize re-executed under their true priors
+    replay_searches: int = 0  # of those, re-executions that searched (not a row prefix)
 
     @property
     def routed(self) -> RoutedBatch:
@@ -206,6 +216,7 @@ class StageCounts:
 
     routed: int = 0  # queries routed
     replayed: int = 0  # of those, re-executed by finalize's replay pass
+    replay_searches: int = 0  # of the replays, those that searched a backend
     search_calls: int = 0  # search_batch calls, replays included
     search_calls_by_backend: dict[str, int] = dataclasses.field(default_factory=dict)
     # per-backend cache hit/miss/eviction totals (CachedBackend only)
@@ -217,6 +228,7 @@ class StageCounts:
         """Fold one finalized batch's counters in."""
         self.routed += decoded.routed.n
         self.replayed += decoded.replayed
+        self.replay_searches += decoded.replay_searches
         _fold_searches(self, decoded)
 
 
@@ -244,6 +256,9 @@ def execute_one(
     query: str,
     routed_idx: int,
     reference: str | None,
+    *,
+    row: tuple[np.ndarray, np.ndarray] | None = None,
+    query_vec: np.ndarray | None = None,
 ) -> DecodedBatch:
     """Run one routed query through retrieve → assemble → decode.
 
@@ -253,6 +268,13 @@ def execute_one(
     choices. Embeds on the caller's thread (only ``route``/``finalize`` may
     call this: the embedder cache is confined to those boundaries).
 
+    With ``row`` (a nested backend's row at least as deep as the bundle's
+    k, searched for this query in its batch), the retrieve stage is
+    skipped: ``assemble`` slices the row as it slices a first-pass row.
+    ``query_vec`` is the vector that search embedded, if any, so the query
+    bills its embedding as a searched replay would; without ``row`` it is
+    not read.
+
     Returns the one-element :class:`DecodedBatch` (execution at index 0),
     so the caller can also merge its search/cache counters into the
     enclosing batch's totals.
@@ -261,11 +283,14 @@ def execute_one(
     bundle = engine.catalog[guarded]
     plan: dict[tuple[str, int], list[int]] = {}
     qvecs: dict[int, np.ndarray] = {}
-    if not bundle.skip_retrieval:
+    if row is not None:
+        if query_vec is not None:
+            qvecs[0] = query_vec
+    elif not bundle.skip_retrieval:
         if engine.backends[bundle.backend].requires_query_vecs:
             with TraceAnnotation("repro.embed", n=1):
                 qvecs[0] = np.asarray(engine.embedder.embed([query]), np.float32)[0]
-        plan[(bundle.backend, bundle.top_k)] = [0]
+        plan[(bundle.backend, search_depths(engine).get(bundle.backend, bundle.top_k))] = [0]
     routed = RoutedBatch(
         qid0=qid,
         queries=[query],
@@ -278,7 +303,23 @@ def execute_one(
         query_vecs=qvecs,
         refinement_on=False,
     )
-    return decode(engine, assemble(engine, retrieve(engine, routed)))
+    if row is not None:
+        retrieved = RetrievedBatch(routed=routed, retrievals={0: row}, search_calls=0)
+    else:
+        retrieved = retrieve(engine, routed)
+    return decode(engine, assemble(engine, retrieved))
+
+
+def search_depths(engine: "RAGEngine") -> dict[str, int]:
+    """Backend name → the k a search of it asks for, for each backend that
+    declares ``nested_topk``: the deepest k the catalog routes to it. Every
+    depth on such a backend is then a prefix of one search's row; other
+    backends are searched at each bundle's own k."""
+    depths: dict[str, int] = {}
+    for b in engine.catalog:
+        if not b.skip_retrieval and is_nested(engine.backends[b.backend]):
+            depths[b.backend] = max(depths.get(b.backend, 0), b.top_k)
+    return depths
 
 
 def make_record(
@@ -324,9 +365,10 @@ def route(
 
     The only entry stage: stamps query ids (so pipelined micro-batches keep
     arrival-ordered qids even before earlier batches finalize) and embeds the
-    queries the speculative plan will retrieve for (one embed call per k
+    queries the speculative plan will retrieve for (one embed call per plan
     group, through the engine's query-vector cache). Must be called serially
-    in arrival order.
+    in arrival order. A nested backend's positions form one group at its
+    :func:`search_depths` depth, whatever their bundles' k.
     """
     queries = list(queries)
     with TraceAnnotation("repro.route", qid0=engine._query_counter, n=len(queries)):
@@ -341,11 +383,13 @@ def route(
         )
 
         guarded = [engine.guardrails.pre_execution(int(c)).bundle_index for c in choices]
+        depths = search_depths(engine)
         plan: dict[tuple[str, int], list[int]] = {}
         for i in range(n):
             bundle = engine.catalog[guarded[i]]
             if not bundle.skip_retrieval:
-                plan.setdefault((bundle.backend, bundle.top_k), []).append(i)
+                k = depths.get(bundle.backend, bundle.top_k)
+                plan.setdefault((bundle.backend, k), []).append(i)
         query_vecs: dict[int, np.ndarray] = {}
         for (bname, _k), idxs in plan.items():
             if not engine.backends[bname].requires_query_vecs:
@@ -507,8 +551,9 @@ def _degrade_group(
 
 @_batch_span
 def retrieve(engine: "RAGEngine", routed: RoutedBatch) -> RetrievedBatch:
-    """Backend-grouped search: one batched ``search_batch`` call per
-    (backend, k) group — the dense groups hit the compiled MIPS closures,
+    """Backend-grouped search: one batched ``search_batch`` call per plan
+    group — (backend, k), where a nested backend's k is its deepest routed
+    depth — the dense groups hit the compiled MIPS closures,
     lexical/approximate groups their own batched paths.
 
     Pure — reads only the immutable backends (and their idempotent
@@ -579,6 +624,10 @@ def assemble(engine: "RAGEngine", retrieved: RetrievedBatch) -> AdmittedBatch:
     """Post-retrieval guardrails (low-confidence demotion), passage payload
     fetch, and prompt construction. Pure given the artifact.
 
+    Each row is first cut to its bundle's ``top_k``: a nested backend's row
+    was searched deeper (:func:`search_depths`), and its prefix is what a
+    search at the bundle's k returns.
+
     Positions the retrieve stage degraded assemble under their *fallback*
     bundle (``retrieved.fallback_bundle``): passages come from the rung
     backend that actually answered, and the confidence guardrail applies at
@@ -601,6 +650,7 @@ def assemble(engine: "RAGEngine", retrieved: RetrievedBatch) -> AdmittedBatch:
         did_embed = i in routed.query_vecs
         if not bundle.skip_retrieval:
             scores, ids = retrieved.retrievals[i]
+            scores, ids = scores[: bundle.top_k], ids[: bundle.top_k]
             confidence = float(scores[0]) if scores.size else float("nan")
             post = engine.guardrails.post_retrieval(bundle_idx, confidence)
             if post.demoted:
@@ -693,6 +743,24 @@ def decode(engine: "RAGEngine", admitted: AdmittedBatch) -> DecodedBatch:
 # --------------------------------------------------------------------------- #
 # Stage 5: finalize (mutates: telemetry, billing ledger; replay fix-up)        #
 # --------------------------------------------------------------------------- #
+def _prefix_row(
+    engine: "RAGEngine", decoded: DecodedBatch, i: int, guarded: int
+) -> tuple[np.ndarray, np.ndarray] | None:
+    """Position ``i``'s first-pass row, where it answers bundle ``guarded``
+    exactly: the bundle retrieves from the nested backend that searched the
+    row, the ladder did not serve the position, and the row is at least the
+    bundle's k deep. ``None`` where the replay has to search."""
+    retrieved = decoded.admitted.retrieved
+    bundle = engine.catalog[guarded]
+    if bundle.skip_retrieval or i in retrieved.fallback_bundle or i not in retrieved.retrievals:
+        return None
+    first = engine.catalog[decoded.routed.guarded[i]]
+    if first.backend != bundle.backend or not is_nested(engine.backends[bundle.backend]):
+        return None
+    row = retrieved.retrievals[i]
+    return row if bundle.top_k <= len(row[1]) else None
+
+
 @_batch_span
 def finalize(engine: "RAGEngine", decoded: DecodedBatch) -> "list[EngineResponse]":
     """Exact replay + commit. Must be called serially, in arrival order.
@@ -704,8 +772,10 @@ def finalize(engine: "RAGEngine", decoded: DecodedBatch) -> "list[EngineResponse
     the telemetry stream on a clone, re-routes each position with its true
     priors (microseconds via the numpy mirror), and re-executes only the
     mispredictions — typically none; under a deep pipeline, whatever the
-    staleness of the speculative priors required. Then billing, realized
-    utility, telemetry append, and response assembly.
+    staleness of the speculative priors required. A re-execution that only
+    changes the depth on a nested backend is served from the position's
+    first-pass row (:func:`_prefix_row`) and searches nothing. Then billing,
+    realized utility, telemetry append, and response assembly.
     """
     from repro.serving.engine import EngineResponse
 
@@ -734,14 +804,21 @@ def finalize(engine: "RAGEngine", decoded: DecodedBatch) -> "list[EngineResponse
                 guarded = engine.guardrails.pre_execution(choice).bundle_index
                 ex = decoded.exec_cache.get((i, guarded))
                 if ex is None:
-                    with TraceAnnotation("repro.replay", qid=qid0 + i):
-                        sub = execute_one(engine, qid0 + i, queries[i], choice, refs[i])
+                    row = _prefix_row(engine, decoded, i, guarded)
+                    searched = row is None and not engine.catalog[guarded].skip_retrieval
+                    with TraceAnnotation("repro.replay", qid=qid0 + i, searched=int(searched)):
+                        sub = execute_one(
+                            engine, qid0 + i, queries[i], choice, refs[i],
+                            row=row, query_vec=routed.query_vecs.get(i),
+                        )
                     ex = sub.executions[0]
                     # fold the one-element replay execution's search/cache
                     # activity into the batch totals (its plan is empty for
-                    # skip-retrieval bundles, so the merge is a no-op there)
+                    # skip-retrieval bundles and served rows, so the merge
+                    # is a no-op there)
                     _fold_searches(decoded, sub)
                     decoded.replayed += 1
+                    decoded.replay_searches += searched
                     decoded.exec_cache[(i, guarded)] = ex
                 executions[i] = ex
             sim.log(make_record(engine, qid0 + i, queries[i], executions[i], 0.0, 0.0))

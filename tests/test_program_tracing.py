@@ -4,7 +4,8 @@
   ``answer_batch``, with their ids and nesting.
 * ``answer_batch`` and :class:`StagePipeline` count the same queries alike
   (:class:`~repro.serving.stages.StageCounts`).
-* ``replayed`` counts exactly the re-executions finalize makes.
+* ``replayed`` counts exactly the re-executions finalize makes, and
+  ``replay_searches`` those of them that searched.
 * The serve CLI prints the engine's counts.
 * The search programs carry the ``score``/``select``/``merge`` scopes.
 """
@@ -61,6 +62,7 @@ def traced(tmp_path_factory):
     qid0 = engine._query_counter
     calls_before = engine.counts.search_calls
     replayed_before = engine.counts.replayed
+    replay_searches_before = engine.counts.replay_searches
     trace_dir = tmp_path_factory.mktemp("trace")
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
@@ -74,6 +76,7 @@ def traced(tmp_path_factory):
         "qid0": qid0,
         "search_calls": engine.counts.search_calls - calls_before,
         "replayed": engine.counts.replayed - replayed_before,
+        "replay_searches": engine.counts.replay_searches - replay_searches_before,
     }
 
 
@@ -87,12 +90,15 @@ def test_stage_spans_share_the_batch_ids(traced):
     assert [s[0] for s in batch] == ["repro.route", "repro.finalize"]
     for _, _, _, ids in batch:
         assert ids == {"qid0": qid0, "n": len(QUERIES)}
-    # the batch's own middle stages carry its ids; a replay's carry the query's
-    middle = [s for s in spans if s[0] == "repro.retrieve"]
-    assert middle[0][3] == {"qid0": qid0, "n": len(QUERIES)}
+    # the batch's own middle stages carry its ids; a replay's carry the
+    # query's, and only a replay that searched runs the retrieve stage
     replays = [s for s in spans if s[0] == "repro.replay"]
-    assert sorted(s[3]["qid0"] for s in middle[1:]) == sorted(r[3]["qid"] for r in replays)
-    assert all(s[3]["n"] == 1 for s in middle[1:])
+    for stage, replayed in (("retrieve", [r for r in replays if r[3]["searched"]]),
+                            ("assemble", replays), ("decode", replays)):
+        middle = [s for s in spans if s[0] == f"repro.{stage}"]
+        assert middle[0][3] == {"qid0": qid0, "n": len(QUERIES)}
+        assert sorted(s[3]["qid0"] for s in middle[1:]) == sorted(r[3]["qid"] for r in replayed)
+        assert all(s[3]["n"] == 1 for s in middle[1:])
 
 
 def test_spans_nest_as_the_stages_call_each_other(traced):
@@ -117,6 +123,8 @@ def test_span_counts_match_the_engine_counts(traced):
     spans = traced["spans"]
     assert sum(s[0] == "repro.search" for s in spans) == traced["search_calls"]
     assert sum(s[0] == "repro.replay" for s in spans) == traced["replayed"] > 0
+    searched = [s for s in spans if s[0] == "repro.replay" and s[3]["searched"]]
+    assert len(searched) == traced["replay_searches"]
 
 
 @pytest.mark.parametrize("batch", [7, 28])
@@ -140,9 +148,9 @@ def test_replayed_counts_each_re_execution(refine, monkeypatch):
     executed = []
     real = stages.execute_one
 
-    def spy(engine_, qid, *rest):
+    def spy(engine_, qid, *rest, **served):
         executed.append(qid)
-        return real(engine_, qid, *rest)
+        return real(engine_, qid, *rest, **served)
 
     monkeypatch.setattr(stages, "execute_one", spy)
     before = engine.counts.replayed
@@ -169,6 +177,7 @@ def test_serve_cli_prints_the_engines_counts(tmp_path):
     assert c.routed == len(QUERIES) and c.replayed > 0
     assert (f"served: {c.routed} routed, {c.replayed} replayed "
             f"({100 * c.replayed / c.routed:.2f}%), "
+            f"{c.replay_searches} replay searches, "
             f"searches by backend {c.search_calls_by_backend}") in proc.stdout.splitlines()
 
 
@@ -190,6 +199,7 @@ def test_serve_cli_prints_the_dense_shard_counters(tmp_path):
     assert shards.searches == c.search_calls_by_backend["dense"] > 0
     assert (f"served: {c.routed} routed, {c.replayed} replayed "
             f"({100 * c.replayed / c.routed:.2f}%), "
+            f"{c.replay_searches} replay searches, "
             f"searches by backend {c.search_calls_by_backend}, "
             f"dense shards {shards.as_dict()}") in proc.stdout.splitlines()
 
